@@ -45,5 +45,7 @@ def distortion(w, w_hat, sigma_x) -> float:
     w = np.asarray(w, dtype=np.float64)
     err = w - np.asarray(w_hat, dtype=np.float64)
     a, n = err.shape
-    return float(np.einsum("ij,jk,ik->", err,
-                           np.asarray(sigma_x, np.float64), err) / (a * n))
+    # (err @ Σ) · err through BLAS: the three-operand einsum without a
+    # contraction path loops over i, j, k in C and takes minutes at d_ff
+    return float(np.sum((err @ np.asarray(sigma_x, np.float64)) * err)
+                 / (a * n))

@@ -13,12 +13,12 @@ from .fault import (Heartbeat, RestartPolicy, StragglerMonitor,
                     run_with_restarts)
 from .sharding import (batch_spec, current_mesh, default_rules,
                        in_manual_axes, logical_shard, manual_axes,
-                       manual_axis_info, shard_map, spec_for_axes, use_mesh)
+                       manual_axis_info, spec_for_axes, use_mesh)
 
 __all__ = [
     "batch_spec", "current_mesh", "default_rules", "in_manual_axes",
-    "logical_shard", "manual_axes", "manual_axis_info", "shard_map",
-    "spec_for_axes", "use_mesh",
+    "logical_shard", "manual_axes", "manual_axis_info", "spec_for_axes",
+    "use_mesh",
     "cleanup_old", "latest_step", "list_steps", "read_manifest",
     "restore_checkpoint", "save_checkpoint",
     "Heartbeat", "RestartPolicy", "StragglerMonitor", "run_with_restarts",

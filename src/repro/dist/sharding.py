@@ -37,7 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 __all__ = [
     "Axis", "default_rules", "spec_for_axes", "batch_spec",
-    "use_mesh", "current_mesh", "logical_shard", "shard_map",
+    "use_mesh", "current_mesh", "logical_shard",
     "manual_axes", "in_manual_axes", "manual_axis_info",
 ]
 
@@ -231,28 +231,3 @@ def logical_shard(x, *axes: Optional[str]):
         entries.append(entry)
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, P(*entries)))
-
-
-# ---------------------------------------------------------------------------
-# shard_map compatibility
-# ---------------------------------------------------------------------------
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  The flag
-    means the same thing (skip the replication-consistency check, needed
-    around all_to_all collectives whose VMA inference is conservative).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this proves, without hardware:
@@ -16,7 +12,19 @@ Usage:
 
 Writes one JSON per cell: experiments/dryrun/<arch>__<shape>__<mesh>.json
 (existing files are skipped — the grid is resumable).
+
+The dry-run always runs on 512 virtual CPU devices, also on a machine with
+an accelerator: it pins ``JAX_PLATFORMS=cpu`` and appends the forced device
+count to the caller's ``XLA_FLAGS``.
 """
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
+# ^ MUST precede any jax import: jax locks the device count on first init.
+
 import argparse
 import json
 import sys

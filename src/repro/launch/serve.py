@@ -32,6 +32,9 @@ with ``--degrade`` (both subsystems hot-swap the served tree).
 
 All engines are built from ONE :class:`repro.serve.EngineConfig` —
 this driver is the reference for the config-first construction API.
+Weights are initialized in bfloat16 (norm scales stay f32) and the KV
+cache is bfloat16, so ``--wbits 16`` serves genuine bf16; the quantized
+rungs are built from the same bf16 tree.
 
     PYTHONPATH=src python -m repro.launch.serve --arch minicpm-2b --reduced \
         --requests 6 --wbits 4 --prefill-chunk 8 --continuous \
@@ -46,11 +49,13 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.configs import get_config
 from repro.dist.fault import RestartPolicy
 from repro.dist.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params, split_tree
 from repro.quant import quantize_params_tree, qweight_bytes
@@ -59,7 +64,19 @@ from repro.serve import (ContinuousEngine, DegradePolicy, EngineConfig,
                          ResilienceConfig, ServeEngine, build_bit_ladder,
                          build_sharded_decode_fns, engine_from_plan,
                          integer_allgathers, lower_decode_hlo,
-                         shard_params_tree, sigma_threshold_detectors)
+                         params_pspecs, shard_params_tree,
+                         sigma_threshold_detectors)
+
+#: weight and KV-cache dtype of every engine this driver builds
+SERVE_DTYPE = jnp.bfloat16
+
+
+def serving_params(cfg):
+    """The driver's master weights: bf16 matmul weights and embedding,
+    f32 norm scales, from the fixed seed 0."""
+    params, _ = split_tree(init_params(cfg, jax.random.PRNGKey(0),
+                                       SERVE_DTYPE))
+    return params
 
 
 def add_obs_flags(ap: argparse.ArgumentParser) -> None:
@@ -249,28 +266,34 @@ def main_mesh(args, cfg):
     # the plain single-device program over the sharded tree.
     mesh = make_host_mesh(model_parallel=len(jax.devices()))
     shards = int(mesh.shape["model"])
-    params, _ = split_tree(init_params(cfg, jax.random.PRNGKey(0)))
-    params = _quantize_for_wbits(params, args.wbits)
+    params = _quantize_for_wbits(serving_params(cfg), args.wbits)
     params = shard_params_tree(params, shards)
     qb, _ = qweight_bytes(params)
     print(f"mesh serving: {shards}-way in-feature sharding on {mesh} "
           f"({qb/1e6:.2f} MB stored, per-shard pad included)")
+    # the mesh engine's copy is placed on the mesh once, so no dispatch
+    # moves weight shards between devices; the oracle keeps the tree on
+    # the default device
+    placed = jax.device_put(params, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), params_pspecs(params),
+        is_leaf=lambda x: isinstance(x, PartitionSpec)))
     max_len = args.prompt_len + args.max_new + 2
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab, args.prompt_len).astype(np.int32)
                for _ in range(args.requests)]
 
     base = EngineConfig(n_slots=args.slots, max_len=max_len,
+                        cache_dtype=SERVE_DTYPE,
                         prefill_chunk=args.prefill_chunk or None,
                         resilience=resilience_from_args(args, params))
 
-    def serve(decode_fns, tag):
+    def serve(tree, decode_fns, tag):
         econfig = base
         if decode_fns is not None:
             econfig = dataclasses.replace(base, decode_fn=decode_fns[0],
                                           decode_chunk_fn=decode_fns[1])
         cls = ContinuousEngine if args.continuous else ServeEngine
-        eng = cls(cfg, params, config=econfig)
+        eng = cls(cfg, tree, config=econfig)
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=p.copy(),
                                max_new_tokens=args.max_new))
@@ -279,38 +302,51 @@ def main_mesh(args, cfg):
         dt = time.perf_counter() - t0
         toks = sum(len(r.out_tokens) for r in done)
         print(f"  {tag}: {len(done)} requests, {toks} tokens in {dt:.2f}s")
-        return {r.rid: list(r.out_tokens) for r in done}
+        return eng, {r.rid: list(r.out_tokens) for r in done}
 
-    oracle = serve(None, "single-device oracle")
-    fns = build_sharded_decode_fns(cfg, params, mesh)
-    meshed = serve(fns, f"{shards}-shard mesh")
+    oracle_eng, oracle = serve(params, None, "single-device oracle")
+    fns = build_sharded_decode_fns(cfg, placed, mesh)
+    mesh_eng, meshed = serve(placed, fns, f"{shards}-shard mesh")
     identical = oracle == meshed
     print(f"  streams bit-identical: {identical}")
+    for rid in sorted(oracle):
+        a, b = oracle[rid], meshed.get(rid, [])
+        at = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                  None if len(a) == len(b) else min(len(a), len(b)))
+        if at is not None:
+            print(f"  first divergence: rid={rid} token {at} "
+                  f"(oracle {a[at:at + 4]} vs mesh {b[at:at + 4]})")
+            break
+    probe = np.stack(prompts[:1])
+    dl = np.abs(np.asarray(oracle_eng.prefill_logits(probe), np.float32)
+                - np.asarray(mesh_eng.prefill_logits(probe), np.float32))
+    print(f"  prefill logits max |oracle - mesh| = {float(dl.max())!r}")
 
     # collective audit: NO integer (weight-payload) all-gather may appear
     # on the compiled decode path — weights stay put, activations move
-    cache = init_cache(cfg, args.slots, max_len, jnp.float32,
+    cache = init_cache(cfg, args.slots, max_len, SERVE_DTYPE,
                        per_slot=args.continuous)
     tok = jnp.zeros((args.slots, 1), jnp.int32)
-    hlo = lower_decode_hlo(cfg, params, mesh, cache, tok)
+    hlo = lower_decode_hlo(cfg, placed, mesh, cache, tok)
     bad = integer_allgathers(hlo)
     n_ag = sum("all-gather" in ln for ln in hlo.splitlines())
     print(f"  decode HLO: {n_ag} all-gather lines, "
           f"{len(bad)} integer-payload all-gathers")
+    summary = {
+        "shards": shards, "wbits": args.wbits,
+        "continuous": bool(args.continuous),
+        "weight_bytes": int(qb),
+        "weight_formats": leaf_format_histogram(params),
+        "inventory": leaf_inventory(params),
+        "streams_oracle": oracle, "streams_mesh": meshed,
+        "identical": identical,
+        "allgather_lines": int(n_ag),
+        "integer_allgathers": bad,
+        "prefill_logits_max_abs_diff": float(dl.max()),
+    }
     if args.mesh_json:
-        payload = {
-            "shards": shards, "wbits": args.wbits,
-            "continuous": bool(args.continuous),
-            "weight_bytes": int(qb),
-            "weight_formats": leaf_format_histogram(params),
-            "inventory": leaf_inventory(params),
-            "streams_oracle": oracle, "streams_mesh": meshed,
-            "identical": identical,
-            "allgather_lines": int(n_ag),
-            "integer_allgathers": bad,
-        }
         with open(args.mesh_json, "w") as f:
-            json.dump(payload, f, indent=1, sort_keys=True)
+            json.dump(summary, f, indent=1, sort_keys=True)
         print(f"wrote {args.mesh_json}")
     obs_export(args)
     if not identical:
@@ -318,10 +354,13 @@ def main_mesh(args, cfg):
     if bad:
         raise SystemExit("weight payload bytes crossed devices:\n"
                          + "\n".join(bad))
-    return meshed
+    return summary
 
 
 def main(argv=None):
+    """Run the driver; returns ``(engine, finished requests)``, or with
+    ``--mesh`` the comparison summary that ``--mesh-json`` writes."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -367,7 +406,7 @@ def main(argv=None):
     mesh = make_host_mesh()
     rng = np.random.default_rng(0)
     with use_mesh(mesh):
-        params, _ = split_tree(init_params(cfg, jax.random.PRNGKey(0)))
+        params = serving_params(cfg)
         if not args.requant:
             params = _quantize_for_wbits(params, args.wbits)
         # the driver builds exactly ONE EngineConfig; every construction
@@ -375,6 +414,7 @@ def main(argv=None):
         econfig = EngineConfig(
             n_slots=args.slots,
             max_len=args.prompt_len + args.max_new + 2,
+            cache_dtype=SERVE_DTYPE,
             prefill_chunk=args.prefill_chunk or None,
             resilience=resilience_from_args(args, params),
             requant=requant_from_args(args))
@@ -452,7 +492,7 @@ def main(argv=None):
         for r in done[:4]:
             print(f"  rid={r.rid} out={r.out_tokens[:8]}")
         obs_export(args)
-        return done
+        return eng, done
 
 
 if __name__ == "__main__":

@@ -22,12 +22,14 @@ from repro.dist.checkpoint import latest_step, restore_checkpoint, \
     save_checkpoint
 from repro.dist.fault import Heartbeat, StragglerMonitor
 from repro.dist.sharding import use_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import init_params, split_tree
 from repro.train import AdamWConfig, TrainState, adamw_init, make_train_step
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")

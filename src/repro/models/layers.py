@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist.sharding import logical_shard, shard_map
+from repro.dist.sharding import logical_shard
 
 __all__ = [
     "Px", "split_tree", "KeyGen",
@@ -772,7 +772,7 @@ def _moe_a2a(p, x, mesh, *, n_experts, top_k, capacity_factor, activation,
     else:
         ws = (p["w_in"], p["w_out"])
         w_specs = (P("model", None, None),) * 2
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, "model", None), P()) + w_specs,
         out_specs=P(dp, "model", None),

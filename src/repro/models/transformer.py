@@ -70,30 +70,35 @@ def _attn_kwargs(cfg):
                 head_dim=cfg.resolved_head_dim, rope_theta=cfg.rope_theta)
 
 
-def _decoder_layer_init(cfg, key, stack):
+def _decoder_layer_init(cfg, key, stack, dtype):
+    """Stacked decoder layers; matmul weights in ``dtype``, norms in f32."""
     kg = KeyGen(key)
     p = {
         "ln_attn": _stacked_norm_init(cfg, stack),
         "attn": attention_init(kg(), cfg.d_model, cfg.n_heads, cfg.n_kv,
                                cfg.resolved_head_dim, bias=cfg.qkv_bias,
-                               out_bias=cfg.out_bias, stack=stack),
+                               out_bias=cfg.out_bias, dtype=dtype,
+                               stack=stack),
         "ln_mlp": _stacked_norm_init(cfg, stack),
     }
     if cfg.n_experts:
         p["moe"] = moe_init(kg(), cfg.d_model, cfg.d_ff, cfg.n_experts,
-                            gated=cfg.gated_mlp, stack=stack)
+                            gated=cfg.gated_mlp, dtype=dtype, stack=stack)
     else:
         p["mlp"] = mlp_init(kg(), cfg.d_model, cfg.d_ff, gated=cfg.gated_mlp,
-                            bias=cfg.out_bias, stack=stack)
+                            bias=cfg.out_bias, dtype=dtype, stack=stack)
     return p
 
 
 def init_params(cfg: ArchConfig, key, dtype=jnp.float32):
+    """Px tree for ``cfg``.  ``dtype`` sets the embedding and, for the
+    decoder families (dense/moe/vlm), every layer matmul weight."""
     kg = KeyGen(key)
     params: Dict[str, Any] = {"embed": embed_init(kg(), cfg.padded_vocab,
                                                   cfg.d_model, dtype)}
     if cfg.family in ("dense", "moe", "vlm"):
-        params["layers"] = _decoder_layer_init(cfg, kg(), cfg.n_layers)
+        params["layers"] = _decoder_layer_init(cfg, kg(), cfg.n_layers,
+                                               dtype)
         params["ln_f"] = _norm_init(cfg)
     elif cfg.family == "ssm":
         blk = rk.rwkv6_init(kg(), cfg.d_model, cfg.d_ff,

@@ -228,6 +228,18 @@ class _EngineBase:
             self._fmt_bytes = weight_format_bytes(self.params)
         return self._fmt_bytes
 
+    def prefill_logits(self, prompts: np.ndarray):
+        """Last-token logits of equal-length ``prompts`` (B, S) through
+        this engine's own prefill dispatches on a fresh cache — the
+        served logits to hold against a full-sequence forward."""
+        cache = init_cache(self.cfg, prompts.shape[0], self.max_len,
+                           self.cache_dtype)
+        logits, _, _ = _run_prefill(self._decode, self._decode_chunk,
+                                    self.params, cache,
+                                    np.asarray(prompts, np.int32),
+                                    self.prefill_chunk)
+        return logits
+
     # -- resilience (DESIGN.md §12) ----------------------------------------
 
     def _init_resilience(self, resilience: Optional[ResilienceConfig]):

@@ -52,7 +52,7 @@ from jax.sharding import PartitionSpec as P
 from repro import obs
 from repro.core.packing import (shard_planar_codes_jnp, unpack_int2_planar_jnp,
                                 unpack_int3_planar_jnp, unpack_int4_planar_jnp)
-from repro.dist.sharding import manual_axes, shard_map
+from repro.dist.sharding import manual_axes
 from repro.models.transformer import decode_chunk, decode_step
 from repro.quant.qlinear import _eligible, is_kshard_qweight, is_qweight
 
@@ -314,7 +314,7 @@ def build_sharded_decode_fns(cfg, params, mesh, *, axis_name: str = "model"):
                                      cache_sharded=cache_sharded):
                         return fn(cfg, p_, c_, t_)
 
-                hit = compiled[key] = jax.jit(shard_map(
+                hit = compiled[key] = jax.jit(jax.shard_map(
                     body, mesh=mesh,
                     in_specs=(pspecs, cspecs, P()),
                     out_specs=(P(), cspecs),
@@ -378,7 +378,7 @@ def lower_decode_hlo(cfg, params, mesh, cache, token, *,
                          cache_sharded=cache_sharded):
             return fn(cfg, p_, c_, t_)
 
-    jitted = jax.jit(shard_map(body, mesh=mesh,
+    jitted = jax.jit(jax.shard_map(body, mesh=mesh,
                                in_specs=(pspecs, cspecs, P()),
                                out_specs=(P(), cspecs), check_vma=False))
     return jitted.lower(params, cache, token).compile().as_text()

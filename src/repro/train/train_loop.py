@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.dist.sharding import shard_map
 from repro.models import loss_fn
 from .grad_compress import compressed_psum_tree, init_error_buf
 from .optimizer import AdamWConfig, AdamWState, adamw_init, adamw_update
@@ -128,7 +127,7 @@ def make_compressed_step(cfg: ArchConfig, opt_cfg: AdamWConfig, mesh, *,
         metrics["loss"] = loss
         return new_params, new_opt, err, metrics
 
-    smapped = shard_map(
+    smapped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, rep, pspec_batch),
         out_specs=(rep, rep, rep, rep),

@@ -8,6 +8,7 @@ import pytest
 
 from repro.dist.checkpoint import (cleanup_old, latest_step, list_steps,
                                    restore_checkpoint, save_checkpoint)
+from repro.launch.mesh import make_mesh
 
 
 def _state(seed=0):
@@ -55,7 +56,7 @@ def test_elastic_restore_new_sharding(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     st = _state()
     save_checkpoint(str(tmp_path), 3, st)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shardings = {"params": {"w": NamedSharding(mesh, P("data", None)),
                             "b": NamedSharding(mesh, P())},
                  "step": NamedSharding(mesh, P())}

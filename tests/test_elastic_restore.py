@@ -16,6 +16,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.models import init_params, split_tree
     from repro.models.transformer import param_specs_tree
     from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_mesh
 
     cfg = get_config("minicpm-2b").reduced()
     params, _ = split_tree(init_params(cfg, jax.random.PRNGKey(0)))
@@ -23,7 +24,7 @@ _SCRIPT = textwrap.dedent("""
     save_checkpoint(d, 7, params)          # written replicated (1-dev view)
 
     # restore onto the 2x4 mesh with the model's real FSDP x TP shardings
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with use_mesh(mesh):
         px = init_params(cfg, jax.random.PRNGKey(0))
         _, specs = param_specs_tree(px)

@@ -1,10 +1,7 @@
 """Entropy estimation + Huffman/codecs (paper §4 Entropy coding, Table 6)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (HuffmanCode, codec_bits_lzma, codec_bits_zlib,
                         column_entropies, effective_rate, empirical_entropy,

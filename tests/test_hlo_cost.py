@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_cost import parse_hlo_costs
+from repro.launch.mesh import make_mesh
 
 X = jax.ShapeDtypeStruct((64, 128), jnp.float32)
 W = jax.ShapeDtypeStruct((128, 128), jnp.float32)
@@ -74,7 +75,7 @@ def test_scan_io_bytes_not_trip_inflated():
 
 
 def test_collective_bytes_sharded():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     with mesh:
         def h(x, w):

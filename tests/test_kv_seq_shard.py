@@ -13,13 +13,14 @@ _SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models import decode_step, init_cache, init_params, split_tree
 
     cfg = get_config("qwen2.5-32b").reduced()
     # kv=2 does not divide model=4; buf=8 does → seq-shard path triggers
     cfg = dataclasses.replace(cfg, n_kv=2, n_heads=4)
     params, _ = split_tree(init_params(cfg, jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     toks = [jnp.asarray(np.random.default_rng(1).integers(
         0, cfg.vocab, (2, 1)), jnp.int32) for _ in range(4)]
 

@@ -15,9 +15,10 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
     from repro.dist.sharding import use_mesh
+    from repro.launch.mesh import make_mesh
     from repro.models.layers import moe, moe_init, split_tree
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     E, k, d, ff = 8, 2, 32, 64
     p, _ = split_tree(moe_init(jax.random.PRNGKey(0), d, ff, E))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, d))

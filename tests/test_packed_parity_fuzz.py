@@ -29,10 +29,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pack_codes_jnp
 from repro.kernels.dequant import (dequant_matmul, dequant_matmul_packed_ref,

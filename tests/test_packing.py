@@ -1,9 +1,6 @@
 """Bit-packing round trips (serving storage path)."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (escapes_to_coo, pack_codes, pack_codes_jnp,
                         pack_int4, pack_int4_planar_jnp, unpack_codes,

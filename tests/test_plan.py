@@ -17,10 +17,7 @@ from repro.plan import (MatrixSensitivity, QuantPlan, allocation_distortion,
                         apply_constraints, build_plan, distortion_at_rate,
                         sensitivity_from_matrix, snap_bits, waterfill_bits)
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 
 def flat(name, v, n=32, a=16, w=1.0, **kw):

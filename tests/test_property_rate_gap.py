@@ -5,8 +5,7 @@ The paper's central guarantee: WaterSIC's empirical rate stays within
 limit for EVERY activation covariance — near-singular, near-white, or
 heavy-tailed alike.  tests/test_theory_gap.py pins three hand-picked
 spectra; this module sweeps the property over randomized
-(n, conditioning, spectrum shape, lattice density) draws via hypothesis
-(or the deterministic fixed-seed fallback in containers without it).
+(n, conditioning, spectrum shape, lattice density) draws via hypothesis.
 
 Both sides are asserted: the measured gap never exceeds the 0.255-bit
 bound (upper side, the paper's claim) and never drops materially below it
@@ -14,11 +13,7 @@ bound (upper side, the paper's claim) and never drops materially below it
 bias would mean the distortion or rate accounting is broken).
 """
 import numpy as np
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (GAP_CUBE_BITS, column_entropies, high_rate_bound,
                         plain_watersic, random_covariance)

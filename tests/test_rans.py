@@ -2,10 +2,7 @@
 skewed alphabets (the production coder for WaterSIC code streams)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import empirical_entropy, huffman_bits
 from repro.core.rans import RansCodec

@@ -1,0 +1,79 @@
+"""Compile the serving kernels for a described TPU v5e at minicpm-2b widths.
+
+Nothing runs here.  Each test lowers a public dequant-matmul op's jitted
+body for one chip of a ``v5e:2x2`` topology (which the installed TPU
+compiler describes without hardware) and asserts the Mosaic kernel
+(``tpu_custom_call``) is in the compiled program: the chip's compiler
+accepts the kernel's tiling and VMEM use at real widths, and no XLA
+reference twin took its place.  The ops pick the Pallas branch from
+``jax.default_backend()``, which still reports the CPU here, so each test
+steers that one call to ``"tpu"`` for the duration of its compile.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.dequant import ops
+
+#: minicpm-2b MLP widths: d_model → d_ff (w_gate/w_up) and d_ff → d_model
+MLP_SHAPES = [(2304, 5760), (5760, 2304)]
+DECODE_ROWS = 4
+#: planar payload shape (n, k) → uint8 payload, by nbits (core/packing)
+PAYLOAD = {4: lambda n, k: (n, -(-k // 2)),
+           3: lambda n, k: (n, 3, -(-k // 8)),
+           2: lambda n, k: (n, 1, -(-k // 4))}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler or libtpu held elsewhere
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a chip compile written to the persistent cache cannot be read
+        # back without the chip; keep the cache out of these compiles
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        cc.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", was)
+            cc.reset_cache()
+            # drop traces made with the steered backend before CPU tests
+            # in this process trace the same functions again
+            jax.clear_caches()
+
+
+def _compiled_text(monkeypatch, jitted, args, **static):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return jitted.lower(*args, **static).compile().as_text()
+
+
+@pytest.mark.parametrize("k,n", MLP_SHAPES)
+@pytest.mark.parametrize("nbits", [4, 3, 2])
+def test_packed_kernel_compiles_for_v5e(one_chip, monkeypatch, nbits, k, n):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((DECODE_ROWS, k), jnp.bfloat16),
+            sds(PAYLOAD[nbits](n, k), jnp.uint8),
+            sds((k,), jnp.float32), sds((n,), jnp.float32))
+    text = _compiled_text(monkeypatch, ops._dequant_matmul_packed, args,
+                          nbits=nbits)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("k,n", MLP_SHAPES)
+def test_int8_kernel_compiles_for_v5e(one_chip, monkeypatch, k, n):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sds((DECODE_ROWS, k), jnp.bfloat16), sds((n, k), jnp.int8),
+            sds((k,), jnp.float32), sds((n,), jnp.float32))
+    text = _compiled_text(monkeypatch, ops._dequant_matmul_int8, args)
+    assert "tpu_custom_call" in text

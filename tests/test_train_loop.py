@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs.base import ArchConfig
 from repro.data import DataConfig, global_batch_for_step
+from repro.launch.mesh import make_mesh
 from repro.models import init_params, split_tree
 from repro.train import (AdamWConfig, TrainState, adamw_init,
                          cosine_schedule, make_compressed_step,
@@ -71,7 +72,7 @@ def test_compressed_dp_step_trains():
     """shard_map int8 error-feedback step runs and reduces loss (1-device
     mesh degenerates gracefully; collective logic is exercised)."""
     params, dcfg = _setup(2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     opt = AdamWConfig(lr=5e-3, total_steps=120, warmup_steps=10)
     from repro.train.grad_compress import init_error_buf
     state = TrainState(params=params, opt=adamw_init(params),

@@ -2,10 +2,7 @@
 import numpy as np
 import pytest
 import jax.numpy as jnp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis (see fallback)
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import zsic_numpy, zsic_jax, zsic_lmmse_jax, zsic_lmmse_numpy, \
     zsic_blocked, random_covariance, chol_lower
