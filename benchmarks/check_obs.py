@@ -1,5 +1,5 @@
-"""CI observability gate: Chrome trace + Prometheus exposition + HBM
-counter reconciliation (DESIGN.md §11).
+"""CI observability gate: Chrome trace + Prometheus exposition
+(DESIGN.md §11).
 
 Stdlib-only (no jax / no repro import) audit of the artifacts an
 obs-enabled ``serve_bench.py --quick --json .. --trace-out ..
@@ -19,12 +19,8 @@ obs-enabled ``serve_bench.py --quick --json .. --trace-out ..
    non-negative finite values, and histograms export the summary shape
    (``quantile`` samples plus ``_sum``/``_count``).
 
-3. **HBM reconciliation**: for every ladder format, the
-   ``repro_kernel_hbm_bytes_total{format=..}`` delta the bench snapshot
-   recorded equals (bytes-per-dispatch from check_bytes.py's
-   packing-layout formulas) × (the engine's own dispatch count) —
-   EXACTLY.  The modeled-traffic counters and the storage gate share one
-   accounting vocabulary; any drift between them fails here.
+3. **JSONL metric log** (optional): every record parses and names its
+   kind; histograms carry their quantiles.
 
     python benchmarks/check_obs.py --bench b.json --trace t.json \
         --prom m.prom [--events e.jsonl]
@@ -32,15 +28,8 @@ obs-enabled ``serve_bench.py --quick --json .. --trace-out ..
 import argparse
 import json
 import math
-import os
 import re
-import sys
 
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-from check_bytes import PAYLOAD_BYTES  # noqa: E402  (single bytes truth)
-
-_SNAP_KEY = re.compile(r'^(?P<name>[a-zA-Z_:][a-zA-Z0-9_:]*)'
-                       r'(\{(?P<labels>.*)\})?$')
 _PROM_SAMPLE = re.compile(r'^(?P<name>[a-zA-Z_:][a-zA-Z0-9_:]*)'
                           r'(\{(?P<labels>[^}]*)\})?\s+(?P<value>\S+)$')
 _LABEL = re.compile(r'(\w+)="((?:[^"\\]|\\.)*)"')
@@ -148,69 +137,7 @@ def check_prometheus(path):
 
 
 # ---------------------------------------------------------------------------
-# 3. HBM counter reconciliation (vs check_bytes accounting)
-# ---------------------------------------------------------------------------
-
-
-def _formula_bytes_by_format(inventory):
-    """Per-format total bytes from the SAME layout formulas check_bytes.py
-    gates (payload + f32 scales + escape COO); raw leaves byte-verbatim."""
-    by_fmt = {}
-    for rec in inventory:
-        fmt = rec["format"]
-        if fmt == "raw":
-            b = rec["bytes"]
-        else:
-            st, o, i = rec["stack"], rec["out"], rec["in"]
-            b = (st * PAYLOAD_BYTES[fmt](o, i) + st * (i + o) * 4
-                 + st * rec["esc_capacity"] * 12)
-        by_fmt[fmt] = by_fmt.get(fmt, 0) + b
-    return by_fmt
-
-
-def check_hbm(bench_path):
-    with open(bench_path) as f:
-        data = json.load(f)
-    n_checked = 0
-    for name, entry in sorted(data["ladder"].items()):
-        deltas = entry.get("obs_kernel") or {}
-        if not deltas:
-            raise SystemExit(f"hbm: ladder run {name} recorded no "
-                             f"repro_kernel_* deltas — was the bench run "
-                             f"with observability enabled?")
-        dispatches = entry["dispatches"]
-        expect = _formula_bytes_by_format(entry["inventory"])
-        got = {}
-        for key, delta in deltas.items():
-            m = _SNAP_KEY.match(key)
-            labels = _parse_labels(m.group("labels"))
-            if m.group("name") == "repro_kernel_hbm_bytes_total":
-                got[labels["format"]] = delta
-            elif m.group("name") == "repro_kernel_weight_dispatch_total":
-                if int(delta) != dispatches:
-                    raise SystemExit(
-                        f"hbm: {name}/{labels['format']} dispatch counter "
-                        f"moved {delta}, engine reports {dispatches}")
-        for fmt, nbytes in sorted(expect.items()):
-            want = nbytes * dispatches
-            have = int(got.get(fmt, 0))
-            if have != want:
-                raise SystemExit(
-                    f"hbm: {name}/{fmt}: counter delta {have} B != "
-                    f"accounting {nbytes} B/dispatch x {dispatches} "
-                    f"dispatches = {want} B")
-            n_checked += 1
-        extra = set(got) - set(expect)
-        if extra:
-            raise SystemExit(f"hbm: {name} counted formats {sorted(extra)} "
-                             f"absent from its inventory")
-        print(f"  hbm: {name}: {len(expect)} formats x {dispatches} "
-              f"dispatches reconcile exactly")
-    return n_checked
-
-
-# ---------------------------------------------------------------------------
-# 4. JSONL metric log (optional)
+# 3. JSONL metric log (optional)
 # ---------------------------------------------------------------------------
 
 
@@ -246,10 +173,9 @@ def main(argv=None):
         n_slots = json.load(f)["sched"]["n_slots"]
     check_trace(args.trace, n_slots)
     check_prometheus(args.prom)
-    n = check_hbm(args.bench)
     if args.events:
         check_events(args.events)
-    print(f"check_obs: OK ({n} format-run HBM reconciliations exact)")
+    print("check_obs: OK")
 
 
 if __name__ == "__main__":

@@ -49,10 +49,8 @@ dispatch-count-structural, so it survives the backend change.
 
 With ``--trace-out``/``--metrics-out``/``--events-out`` the bench also
 runs under ``repro.obs`` (DESIGN.md §11) and exports the Chrome trace,
-Prometheus exposition, and JSONL metric log; each ladder run snapshots
-the ``repro_kernel_*`` counter deltas into the JSON so
-``benchmarks/check_obs.py`` can reconcile the modeled HBM counters
-against check_bytes.py's layout accounting exactly.
+Prometheus exposition, and JSONL metric log that
+``benchmarks/check_obs.py`` audits.
 
     python benchmarks/serve_bench.py [--quick] \
         [--json out.json --trace-out trace.json --metrics-out m.prom]
@@ -81,12 +79,6 @@ from repro.serve import (ContinuousEngine, DegradePolicy, EngineConfig,
                          ResilienceConfig, ServeEngine, build_bit_ladder)
 
 
-def _kernel_deltas(before, after):
-    """repro_kernel_* counter movement across one ladder run."""
-    return {k: v - before.get(k, 0.0) for k, v in after.items()
-            if v != before.get(k, 0.0)}
-
-
 def _engine_run(cfg, params, prompts, max_new, chunk, decode_fns=None):
     ec = EngineConfig(n_slots=len(prompts),
                       max_len=prompts[0].size + max_new + 2,
@@ -96,7 +88,6 @@ def _engine_run(cfg, params, prompts, max_new, chunk, decode_fns=None):
     eng = ServeEngine(cfg, params, config=ec)
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p.copy(), max_new_tokens=max_new))
-    snap0 = obs.counters_snapshot("repro_kernel_")
     t0 = time.perf_counter()
     done = eng.run_until_done()
     wall = time.perf_counter() - t0
@@ -108,13 +99,6 @@ def _engine_run(cfg, params, prompts, max_new, chunk, decode_fns=None):
             "prefill_s": st.prefill_s,
             "weight_bytes": eng.weight_bytes,
             "weight_formats": dict(eng.weight_formats),
-            # per-format HBM/dispatch counter movement for this run plus the
-            # engine's own dispatch count — check_obs.py reconciles the two
-            # against the inventory's layout math (exact, not approximate)
-            "obs_kernel": _kernel_deltas(snap0,
-                                         obs.counters_snapshot("repro_kernel_")),
-            "dispatches": sum(s.prefill_calls + s.decode_calls
-                              for s in eng.round_stats),
             "out": {r.rid: tuple(r.out_tokens) for r in done}}
 
 
@@ -358,8 +342,7 @@ def quality_bench(rows_out, cfg, params, quick=False, events_out=None):
     measured/predicted reconciliation band.
 
     Runs inside ``obs.scoped`` so the always-on sampling cannot disturb
-    the surrounding run's counters (check_obs.py reconciles those
-    EXACTLY against the layout accounting).
+    the surrounding run's counters.
     """
     from repro.obs.drift import Threshold
     from repro.plan.sensitivity import collect_sigma_x
@@ -623,8 +606,6 @@ def _json_payload(rows, results):
             "bytes_per_w": res["bytes_per_w"],
             "weight_bytes": res["weight_bytes"],
             "weight_formats": res["weight_formats"],
-            "obs_kernel": res["obs_kernel"],
-            "dispatches": res["dispatches"],
             "inventory": res["inventory"]}
     payload = envelope("serve")
     payload.update({"rows": [list(r) for r in rows], "ladder": ladder,

@@ -104,6 +104,7 @@ def dequant_matmul_pallas(x, z, col_scale, row_scale, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name="dequant_matmul_int8",
     )(x, z, col_scale.reshape(1, k), row_scale.reshape(1, n))
 
 
@@ -222,5 +223,6 @@ def dequant_matmul_packed_pallas(x_groups, payload, s_groups, row_scale, *,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
+        name=f"dequant_matmul_packed_int{nbits}",
     )(x_groups, payload, s_groups.reshape(1, g, kg),
       row_scale.reshape(1, n))

@@ -26,19 +26,10 @@ advantage that the roofline analysis measures.  The packed XLA siblings
 (``dequant_matmul_packed_xla`` / ``_packed3_xla`` / ``_packed2_xla``) are
 thin aliases of the ref-twin with the payload nbits pinned.
 
-Observability (DESIGN.md §11): the public entry points feed the
-``repro_kernel_*`` metric families when ``repro.obs`` is enabled —
-``repro_kernel_dispatch_total{format,path}`` counts Python-level kernel
-entries (every eager call, and every jit TRACE when the matmul is
-embedded in a larger jitted graph — re-dispatches of a cached executable
-never re-enter Python, so in-graph use counts compilations, not steps).
-Per-device-dispatch weight traffic is modeled at the ENGINE level, where
-the step structure is visible: :func:`record_weight_traffic` adds a
-param tree's per-format stored bytes (``weight_format_bytes`` — the same
-``quant.leaf_inventory`` records benchmarks/check_bytes.py audits) to
-``repro_kernel_hbm_bytes_total{format}`` once per forward dispatch, so
-the counter reconciles EXACTLY with the byte-accounting gate
-(benchmarks/check_obs.py asserts it).
+The packed path's padding, splitting and slicing run under the
+``packed_matmul`` scope, and each Pallas call carries its own kernel
+name, so a device trace tells the kernel's own time and its wrapper's
+pads from the rest of the step (DESIGN.md §11).
 """
 from __future__ import annotations
 
@@ -48,8 +39,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro import obs
-
 from .dequant_matmul import (PLANE_GROUPS, dequant_matmul_packed_pallas,
                              dequant_matmul_pallas)
 from .ref import dequant_matmul_packed_ref, dequant_matmul_ref
@@ -58,52 +47,7 @@ __all__ = ["dequant_matmul", "dequant_matmul_packed", "dequant_matmul_xla",
            "dequant_matmul_packed_xla", "dequant_matmul_packed3",
            "dequant_matmul_packed3_xla", "dequant_matmul_packed2",
            "dequant_matmul_packed2_xla", "dequant_matmul_sharded",
-           "payload_nbits", "record_weight_traffic", "weight_format_bytes",
-           "payload_checksums", "verify_payloads"]
-
-#: payload nbits → the leaf-format label shared with quant.leaf_inventory
-#: and benchmarks/check_bytes.py (one vocabulary across all three gates)
-FORMAT_OF_NBITS = {8: "int8", 4: "packed-int4", 3: "packed-int3",
-                   2: "packed-int2"}
-
-
-def _count_dispatch(fmt: str, path: str) -> None:
-    if obs.enabled():
-        obs.counter("repro_kernel_dispatch_total", format=fmt,
-                    path=path).inc()
-
-
-def weight_format_bytes(tree) -> Dict[str, int]:
-    """Serving format → total stored bytes over a param tree.
-
-    Grouped from ``quant.leaf_inventory`` — the identical records the
-    check_bytes.py CI gate audits — so engine-modeled HBM counters and
-    the byte-accounting gate can never use two different byte models.
-    """
-    from repro.quant import leaf_inventory  # lazy: avoids an import cycle
-    out: Dict[str, int] = {}
-    for rec in leaf_inventory(tree):
-        out[rec["format"]] = out.get(rec["format"], 0) + int(rec["bytes"])
-    return out
-
-
-def record_weight_traffic(format_bytes: Dict[str, int],
-                          dispatches: int = 1) -> None:
-    """Model ``dispatches`` forward passes' HBM weight reads.
-
-    Every device dispatch (prefill chunk or decode step) streams the
-    whole weight tree once, so each format's counter grows by its stored
-    bytes × dispatches.  The serving engines call this per round/step
-    with their cached :func:`weight_format_bytes`.
-    """
-    if not obs.enabled() or dispatches <= 0:
-        return
-    for fmt, nbytes in format_bytes.items():
-        obs.counter("repro_kernel_hbm_bytes_total", format=fmt) \
-            .inc(nbytes * dispatches)
-        obs.counter("repro_kernel_weight_dispatch_total", format=fmt) \
-            .inc(dispatches)
-
+           "payload_nbits", "payload_checksums", "verify_payloads"]
 
 def _walk_qweights(tree):
     """(path-string, qweight-dict) pairs in quant.leaf_inventory's path
@@ -207,8 +151,7 @@ def dequant_matmul(x, z, col_scale, row_scale, *, escapes=None,
     ``z`` int8 (n, k) selects the int8 kernel; a uint8 payload selects the
     packed kernel at the nbits its shape encodes (``payload_nbits``).
     ``escapes`` is an optional COO triple (rows, cols, dvals) applied after
-    the kernel.  The eager entry bumps ``repro_kernel_dispatch_total``
-    (format + kernel path) before handing off to the jitted body.
+    the kernel.
     """
     if z.dtype == jnp.uint8:
         return dequant_matmul_packed(
@@ -216,9 +159,6 @@ def dequant_matmul(x, z, col_scale, row_scale, *, escapes=None,
             escapes=escapes, block_m=block_m, block_n=block_n,
             block_k=block_k, prefer_pallas=prefer_pallas,
             interpret=interpret)
-    on_tpu = jax.default_backend() == "tpu"
-    _count_dispatch("int8", "pallas" if prefer_pallas
-                    and (on_tpu or interpret) else "xla")
     return _dequant_matmul_int8(
         x, z, col_scale, row_scale, escapes=escapes, block_m=block_m,
         block_n=block_n, block_k=block_k, prefer_pallas=prefer_pallas,
@@ -262,12 +202,8 @@ def dequant_matmul_packed(x, payload, col_scale, row_scale, *,
     zero-padded to the packed width G·kg before the planar groups are
     split, so every pad column multiplies an all-zero activation column
     and contributes nothing.  The same argument covers the block-align
-    padding of the byte axis.  The eager entry bumps
-    ``repro_kernel_dispatch_total`` before the jitted body.
+    padding of the byte axis.
     """
-    on_tpu = jax.default_backend() == "tpu"
-    _count_dispatch(FORMAT_OF_NBITS[nbits], "pallas" if prefer_pallas
-                    and (on_tpu or interpret) else "ref")
     return _dequant_matmul_packed(
         x, payload, col_scale, row_scale, nbits=nbits, escapes=escapes,
         block_m=block_m, block_n=block_n, block_k=block_k,
@@ -282,33 +218,35 @@ def _dequant_matmul_packed(x, payload, col_scale, row_scale, *,
                            block_m: int = 128, block_n: int = 128,
                            block_k: int = 512, prefer_pallas: bool = True,
                            interpret: bool = False):
-    g = PLANE_GROUPS[nbits]
-    m, k = x.shape
-    n, kg = payload.shape[0], payload.shape[-1]
-    k_packed = g * kg
-    assert k_packed - g < k <= k_packed, (x.shape, payload.shape, nbits)
-    xp = _pad_to(x, k_packed, 1) if k < k_packed else x
-    sp = _pad_to(col_scale, k_packed, 0) if k < k_packed else col_scale
-    on_tpu = jax.default_backend() == "tpu"
-    if prefer_pallas and (on_tpu or interpret):
-        block_kg = min(max(128, block_k // g), max(128, kg))
-        pp = _pad_to(_pad_to(payload, block_n, 0), block_kg, -1)
-        # planar order is group-major, so the grouped view is a reshape —
-        # but the byte-axis block pad must land INSIDE each group
-        xg = _pad_to(_pad_to(xp, block_m, 0).reshape(-1, g, kg),
-                     block_kg, -1)
-        sg = _pad_to(sp.reshape(g, kg), block_kg, -1)
-        tp = _pad_to(row_scale, block_n, 0)
-        out = dequant_matmul_packed_pallas(
-            xg, pp, sg, tp, nbits=nbits, block_m=block_m, block_n=block_n,
-            block_kg=block_kg,
-            interpret=interpret or not on_tpu)[:m, :n]
-    else:
-        out = dequant_matmul_packed_ref(xp, payload, sp, row_scale,
-                                        nbits=nbits)
-    if escapes is not None:
-        out = _apply_escapes(out, x, col_scale, row_scale, escapes)
-    return out
+    with jax.named_scope("packed_matmul"):
+        g = PLANE_GROUPS[nbits]
+        m, k = x.shape
+        n, kg = payload.shape[0], payload.shape[-1]
+        k_packed = g * kg
+        assert k_packed - g < k <= k_packed, (x.shape, payload.shape, nbits)
+        xp = _pad_to(x, k_packed, 1) if k < k_packed else x
+        sp = _pad_to(col_scale, k_packed, 0) if k < k_packed else col_scale
+        on_tpu = jax.default_backend() == "tpu"
+        if prefer_pallas and (on_tpu or interpret):
+            block_kg = min(max(128, block_k // g), max(128, kg))
+            pp = _pad_to(_pad_to(payload, block_n, 0), block_kg, -1)
+            # planar order is group-major, so the grouped view is a
+            # reshape — but the byte-axis block pad must land INSIDE each
+            # group
+            xg = _pad_to(_pad_to(xp, block_m, 0).reshape(-1, g, kg),
+                         block_kg, -1)
+            sg = _pad_to(sp.reshape(g, kg), block_kg, -1)
+            tp = _pad_to(row_scale, block_n, 0)
+            out = dequant_matmul_packed_pallas(
+                xg, pp, sg, tp, nbits=nbits, block_m=block_m,
+                block_n=block_n, block_kg=block_kg,
+                interpret=interpret or not on_tpu)[:m, :n]
+        else:
+            out = dequant_matmul_packed_ref(xp, payload, sp, row_scale,
+                                            nbits=nbits)
+        if escapes is not None:
+            out = _apply_escapes(out, x, col_scale, row_scale, escapes)
+        return out
 
 
 @jax.jit
@@ -387,14 +325,7 @@ def dequant_matmul_sharded(x, z, col_scale=None, row_scale=None, *,
                          "path needs the static shard count (the local z "
                          "block's shard axis is 1)")
     nbits = payload_nbits(z) if z.dtype == jnp.uint8 else None
-    if z.dtype == jnp.uint8:
-        k_loc = col_scale.shape[-1]
-        _count_dispatch(FORMAT_OF_NBITS[nbits], "kshard")
-    elif z.dtype == jnp.int8:
-        k_loc = z.shape[-2]
-        _count_dispatch("int8", "kshard")
-    else:
-        k_loc = z.shape[-2]
+    k_loc = col_scale.shape[-1] if z.dtype == jnp.uint8 else z.shape[-2]
     m, k = x.shape
     total = shards * k_loc
     xp = _pad_to(x, total, 1) if k < total else x

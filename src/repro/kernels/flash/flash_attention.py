@@ -125,4 +125,5 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

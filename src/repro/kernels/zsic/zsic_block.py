@@ -148,5 +148,6 @@ def zsic_block_pallas(y, l_block, alphas, *, block_rows: int = 256,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name=f"zsic_block_{row_select}",
     )(y, l_block, alphas.reshape(1, bn))
     return z, resid

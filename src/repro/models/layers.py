@@ -12,6 +12,7 @@ embedding/unembedding.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
@@ -22,7 +23,7 @@ import numpy as np
 from repro.dist.sharding import logical_shard
 
 __all__ = [
-    "Px", "split_tree", "KeyGen",
+    "Px", "split_tree", "KeyGen", "scoped",
     "rmsnorm_init", "rmsnorm", "layernorm_init", "layernorm",
     "dense_init", "dense",
     "rope", "sinusoidal_positions",
@@ -59,6 +60,19 @@ def split_tree(tree):
     vals = jax.tree.map(lambda p: p.value, tree, is_leaf=_is_px)
     axes = jax.tree.map(lambda p: p.axes, tree, is_leaf=_is_px)
     return vals, axes
+
+
+def scoped(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``, so the
+    device ops it traces carry ``name`` in their ``op_name`` (a fresh scope
+    per call: the context object keeps state and is not shared)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def call(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return call
+    return wrap
 
 
 class KeyGen:
@@ -407,13 +421,14 @@ def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
     buf = buf_loc * _ctx["shards"] if kv_sharded else buf_loc
     pos = jnp.asarray(pos)
     per_slot = pos.ndim == 1
-    q = _split_heads(dense(p["wq"], x_t), n_q, head_dim)
-    k_t = _split_heads(dense(p["wk"], x_t), n_kv, head_dim)
-    v_t = _split_heads(dense(p["wv"], x_t), n_kv, head_dim)
-    posv = pos[:, None] if per_slot else jnp.full((b, 1), pos)
-    if use_rope:
-        q = rope(q, posv, rope_theta)
-        k_t = rope(k_t, posv, rope_theta)
+    with jax.named_scope("attn_proj"):
+        q = _split_heads(dense(p["wq"], x_t), n_q, head_dim)
+        k_t = _split_heads(dense(p["wk"], x_t), n_kv, head_dim)
+        v_t = _split_heads(dense(p["wv"], x_t), n_kv, head_dim)
+        posv = pos[:, None] if per_slot else jnp.full((b, 1), pos)
+        if use_rope:
+            q = rope(q, posv, rope_theta)
+            k_t = rope(k_t, posv, rope_theta)
     slot = pos % buf if window is not None else pos
     if kv_sharded:
         # every row scatters into the LOCAL block: global slot minus this
@@ -443,72 +458,82 @@ def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
                 big, new.astype(big.dtype), slot, axis=1)
     int8_kv = cache.k.dtype == jnp.int8
     k_scale, v_scale = cache.k_scale, cache.v_scale
-    if int8_kv:
-        def q8(x_t):
-            s_t = jnp.max(jnp.abs(x_t), axis=-1, keepdims=True) / 127.0
-            s_t = jnp.maximum(s_t, 1e-12)
-            return (jnp.rint(x_t / s_t).astype(jnp.int8),
-                    s_t.astype(jnp.float32))
-        k_t_c, ks_t = q8(k_t)
-        v_t_c, vs_t = q8(v_t)
-        k = upd(cache.k, k_t_c)
-        v = upd(cache.v, v_t_c)
-        k_scale = upd(cache.k_scale, ks_t)
-        v_scale = upd(cache.v_scale, vs_t)
-    else:
-        k = upd(cache.k, k_t)
-        v = upd(cache.v, v_t)
     from repro.dist.sharding import current_mesh
     from repro.opts import enabled as _opt
-    mesh = current_mesh()
-    msize = dict(getattr(mesh, "shape", {})).get("model", 1) if mesh else 1
-    if _opt("kv_seq_shard") and n_kv % msize and k.shape[1] % msize == 0:
-        # §Perf kv_seq_shard: shard the cache SEQ dim over "model" — avoids
-        # replicating the cache when kv-head count doesn't divide the axis
-        # (GQA kv=8 / MHA 36-40 heads on a 16-way axis)
-        k = logical_shard(k, "batch", "kv_seq", None, None)
-        v = logical_shard(v, "batch", "kv_seq", None, None)
-    else:
-        k = logical_shard(k, "batch", None, "kv_heads", None)
-        v = logical_shard(v, "batch", None, "kv_heads", None)
-    if kv_sharded:
-        # reassemble the global ring buffer for the scores — an
-        # activation-sized gather (this step's K/V), never weights; shard
-        # s holds global slots [s*buf_loc, (s+1)*buf_loc), so the tiled
-        # gather reproduces the oracle's buffer ordering exactly
-        def _gather(a):
-            return jax.lax.all_gather(a, _ctx["axis"], axis=1, tiled=True)
-        k_full, v_full = _gather(k), _gather(v)
-        ks_full = _gather(k_scale) if int8_kv else k_scale
-        vs_full = _gather(v_scale) if int8_kv else v_scale
-    else:
-        k_full, v_full, ks_full, vs_full = k, v, k_scale, v_scale
-    k_eff = (k_full.astype(q.dtype) * ks_full.astype(q.dtype)) \
-        if int8_kv else k_full
-    v_eff = (v_full.astype(q.dtype) * vs_full.astype(q.dtype)) \
-        if int8_kv else v_full
-    scores = _attn_scores(q, k_eff, 1.0 / math.sqrt(head_dim))  # (B,nkv,G,1,buf)
-    idx = jnp.arange(buf)
-    if per_slot:
-        # (B, buf) mask: every slot masks by ITS OWN position
-        if window is not None:
-            age = (slot[:, None] - idx[None, :]) % buf
-            valid = age < jnp.minimum(pos[:, None] + 1, buf)
+    with jax.named_scope("kv_cache"):
+        if int8_kv:
+            def q8(x_t):
+                s_t = jnp.max(jnp.abs(x_t), axis=-1, keepdims=True) / 127.0
+                s_t = jnp.maximum(s_t, 1e-12)
+                return (jnp.rint(x_t / s_t).astype(jnp.int8),
+                        s_t.astype(jnp.float32))
+            k_t_c, ks_t = q8(k_t)
+            v_t_c, vs_t = q8(v_t)
+            k = upd(cache.k, k_t_c)
+            v = upd(cache.v, v_t_c)
+            k_scale = upd(cache.k_scale, ks_t)
+            v_scale = upd(cache.v_scale, vs_t)
         else:
-            valid = idx[None, :] <= pos[:, None]
-        scores = jnp.where(valid[:, None, None, None, :], scores, -1e30)
-    else:
-        if window is not None:
-            # entry j holds absolute position: j + buf*floor((pos - j)/buf) —
-            # valid iff its absolute position ∈ (pos-window, pos]
-            age = (slot - idx) % buf
-            valid = age < jnp.minimum(pos + 1, buf)
+            k = upd(cache.k, k_t)
+            v = upd(cache.v, v_t)
+        mesh = current_mesh()
+        msize = dict(getattr(mesh, "shape", {})).get("model", 1) \
+            if mesh else 1
+        if _opt("kv_seq_shard") and n_kv % msize \
+                and k.shape[1] % msize == 0:
+            # §Perf kv_seq_shard: shard the cache SEQ dim over "model" —
+            # avoids replicating the cache when kv-head count doesn't
+            # divide the axis (GQA kv=8 / MHA 36-40 heads on a 16-way axis)
+            k = logical_shard(k, "batch", "kv_seq", None, None)
+            v = logical_shard(v, "batch", "kv_seq", None, None)
         else:
-            valid = idx <= pos
-        scores = jnp.where(valid[None, None, None, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-    out = _attn_out(probs.astype(x_t.dtype), v_eff)
-    out = dense(p["wo"], out)
+            k = logical_shard(k, "batch", None, "kv_heads", None)
+            v = logical_shard(v, "batch", None, "kv_heads", None)
+    with jax.named_scope("attention"):
+        if kv_sharded:
+            # reassemble the global ring buffer for the scores — an
+            # activation-sized gather (this step's K/V), never weights;
+            # shard s holds global slots [s*buf_loc, (s+1)*buf_loc), so
+            # the tiled gather reproduces the oracle's buffer ordering
+            def _gather(a):
+                return jax.lax.all_gather(a, _ctx["axis"], axis=1,
+                                          tiled=True)
+            k_full, v_full = _gather(k), _gather(v)
+            ks_full = _gather(k_scale) if int8_kv else k_scale
+            vs_full = _gather(v_scale) if int8_kv else v_scale
+        else:
+            k_full, v_full, ks_full, vs_full = k, v, k_scale, v_scale
+        k_eff = (k_full.astype(q.dtype) * ks_full.astype(q.dtype)) \
+            if int8_kv else k_full
+        v_eff = (v_full.astype(q.dtype) * vs_full.astype(q.dtype)) \
+            if int8_kv else v_full
+        # (B, nkv, G, 1, buf)
+        scores = _attn_scores(q, k_eff, 1.0 / math.sqrt(head_dim))
+        idx = jnp.arange(buf)
+        if per_slot:
+            # (B, buf) mask: every slot masks by ITS OWN position
+            if window is not None:
+                age = (slot[:, None] - idx[None, :]) % buf
+                valid = age < jnp.minimum(pos[:, None] + 1, buf)
+            else:
+                valid = idx[None, :] <= pos[:, None]
+            scores = jnp.where(valid[:, None, None, None, :], scores,
+                               -1e30)
+        else:
+            if window is not None:
+                # entry j holds absolute position:
+                # j + buf*floor((pos - j)/buf) — valid iff its absolute
+                # position ∈ (pos-window, pos]
+                age = (slot - idx) % buf
+                valid = age < jnp.minimum(pos + 1, buf)
+            else:
+                valid = idx <= pos
+            scores = jnp.where(valid[None, None, None, None, :], scores,
+                               -1e30)
+        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+        out = _attn_out(probs.astype(x_t.dtype), v_eff)
+    with jax.named_scope("attn_proj"):
+        out = dense(p["wo"], out)
     return out, KVCache(k=k, v=v, k_scale=k_scale, v_scale=v_scale)
 
 
@@ -543,6 +568,7 @@ def mlp_init(key, d_model, d_ff, *, gated=True, bias=False,
     return p
 
 
+@scoped("mlp")
 def mlp(p, x, *, activation="silu"):
     act = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
            "relu2": lambda u: jnp.square(jax.nn.relu(u))}[activation]
@@ -802,6 +828,7 @@ def embed(p, tokens):
     return jnp.take(p["w"], tokens, axis=0)
 
 
+@scoped("lm_head")
 def unembed(p, x, vocab: Optional[int] = None):
     logits = x @ p["w"].astype(x.dtype).T
     logits = logical_shard(logits, "batch", "seq", "vocab")

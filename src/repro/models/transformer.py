@@ -36,7 +36,7 @@ from .layers import (KVCache, KeyGen, Px, attention_decode, attention_init,
                      attention_train, cross_attention_decode, dense,
                      dense_init, embed, embed_init, layernorm, layernorm_init,
                      mlp, mlp_init, moe, moe_init, rmsnorm, rmsnorm_init,
-                     sinusoidal_positions, split_tree, unembed)
+                     scoped, sinusoidal_positions, split_tree, unembed)
 
 __all__ = ["init_params", "forward_train", "loss_fn", "prefill", "init_cache",
            "decode_step", "param_specs_tree", "cache_write_slot",
@@ -383,6 +383,7 @@ def _kv_buf(cfg, batch, buf_len, dtype, n_layers=None):
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
+@scoped("kv_cache")
 def init_cache(cfg: ArchConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16, *, per_slot: bool = False) -> DecodeCache:
     """Fresh decode cache.  ``per_slot=True`` makes ``pos`` a (batch,) int32
@@ -424,6 +425,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int,
     raise ValueError(cfg.family)
 
 
+@scoped("kv_cache")
 def cache_write_slot(cache: DecodeCache, sub: DecodeCache,
                      slot) -> DecodeCache:
     """Graft a batch-1 ``sub`` cache into row ``slot`` of a per-slot cache.
@@ -448,6 +450,7 @@ def cache_write_slot(cache: DecodeCache, sub: DecodeCache,
     return DecodeCache(kv, pos, extras)
 
 
+@scoped("kv_cache")
 def cache_reset_slot(cache: DecodeCache, slot) -> DecodeCache:
     """Zero row ``slot`` of a per-slot cache (eviction hygiene).
 

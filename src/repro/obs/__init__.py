@@ -1,8 +1,8 @@
 """repro.obs — unified tracing, metrics, and timeline export (DESIGN.md §11).
 
 One process-wide tracer + metrics registry behind a module facade, OFF
-by default: every instrumentation site in the serving engines, the plan
-executor, and the dequant dispatch goes through these helpers, and when
+by default: every instrumentation site in the serving engines and the
+plan executor goes through these helpers, and when
 disabled each helper is a boolean check returning a shared no-op
 singleton — the engines' token streams, dispatch counts, and RoundStats
 are byte-identical with the subsystem off (asserted in tests/test_obs_
@@ -11,8 +11,13 @@ integration.py) and the per-call overhead is a bare function call
 
 Enable with ``REPRO_OBS=1`` in the environment or :func:`enable` in
 code (the ``--trace-out``/``--metrics-out`` flags of launch/serve.py,
-launch/plan.py and benchmarks/serve_bench.py do the latter).  Three
-export surfaces:
+launch/plan.py and benchmarks/serve_bench.py do the latter).
+
+Spans also land on the JAX profiler's clock: while a profiler session
+collects (``jax.profiler.trace`` / ``start_trace``), :func:`span` enters
+a ``jax.profiler.TraceAnnotation`` of the same name, enabled or not, so
+the device trace and the program's spans line up.  With neither on it
+returns ``NULL_SPAN``.  Three export surfaces:
 
 * :func:`write_trace` — Chrome trace-event JSON (Perfetto-loadable
   timeline: per-slot serving lanes, per-task executor spans);
@@ -23,10 +28,7 @@ export surfaces:
 
 Metric families follow the §11 naming scheme: ``repro_serve_*`` (engine
 lifecycle: TTFT/TPOT histograms, slot/queue gauges, admission/eviction
-counters), ``repro_plan_*`` (executor tasks/retries/stragglers), and
-``repro_kernel_*`` (dequant dispatch + modeled HBM weight traffic,
-reconciled against benchmarks/check_bytes.py accounting by
-benchmarks/check_obs.py).
+counters) and ``repro_plan_*`` (executor tasks/retries/stragglers).
 """
 from __future__ import annotations
 
@@ -35,12 +37,12 @@ import os
 from typing import Dict
 
 from .metrics import Counter, Gauge, Histogram, Registry
-from .trace import NULL_SPAN, Tracer
+from .trace import NULL_SPAN, Span, TraceAnnotation, Tracer
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "Tracer",
            "enabled", "enable", "disable", "reset", "registry", "tracer",
            "scoped",
-           "span", "complete", "instant", "counter", "gauge", "histogram",
+           "span", "instant", "counter", "gauge", "histogram",
            "counters_snapshot", "prometheus_text", "jsonl_lines",
            "write_trace", "write_prometheus", "write_jsonl"]
 
@@ -103,9 +105,9 @@ def scoped(*, enable_obs: bool = False):
     An isolated measurement scope: a benchmark section that must not
     pollute the surrounding run's counters (e.g. serve_bench's quality
     cells run obs-enabled even when the ladder runs obs-off, and the
-    obs-smoke gate's EXACT HBM reconciliation would otherwise see their
-    traffic).  The enabled flag is saved/restored too; ``enable_obs``
-    turns recording on inside the scope.  Yields ``(registry, tracer)``.
+    surrounding run's counters would otherwise see their traffic).  The
+    enabled flag is saved/restored too; ``enable_obs`` turns recording
+    on inside the scope.  Yields ``(registry, tracer)``.
     """
     global _registry, _tracer, _enabled
     saved = (_registry, _tracer, _enabled)
@@ -121,15 +123,23 @@ def scoped(*, enable_obs: bool = False):
 # -- recording facade (each helper no-ops when disabled) --------------------
 
 
+#: True while a profiler session collects (jaxlib's TraceMe check)
+_profiling = TraceAnnotation.is_enabled
+
+
 def span(name: str, **args):
-    """``with obs.span("serve.prefill", slot=3): …`` — times the body."""
-    return _tracer.span(name, **args) if _enabled else NULL_SPAN
-
-
-def complete(name: str, t0_s: float, t1_s: float, **args) -> None:
-    """Adopt an existing perf_counter stamp pair as a complete span."""
+    """``with obs.span("serve.prefill", slot=3) as sp: …`` — times the body
+    into the Chrome trace when enabled, and into the profiler's trace
+    while a session collects.  ``tid=`` picks the Chrome lane, ``alias=``
+    records the Chrome event under a second name too; ``sp.stamp(t0, t1)``
+    hands the Chrome event the caller's own stamps, ``sp.set(**args)``
+    adds arguments known only inside the body."""
     if _enabled:
-        _tracer.complete(name, t0_s, t1_s, **args)
+        return _tracer.span(name, annotate=_profiling(), **args)
+    if _profiling():
+        args.pop("alias", None)
+        return Span(None, name, args.pop("tid", None), args, annotate=True)
+    return NULL_SPAN
 
 
 def instant(name: str, **args) -> None:

@@ -1,4 +1,5 @@
-"""Structured tracing: nestable spans → Chrome trace-event JSON.
+"""Structured tracing: nestable spans → Chrome trace-event JSON and the
+JAX profiler's own trace.
 
 The :class:`Tracer` records *complete* events (``ph: "X"``) and
 *instant* events (``ph: "i"``) in the Chrome Trace Event format —
@@ -8,15 +9,16 @@ timeline for free (DESIGN.md §11).
 
 Timestamps are ``time.perf_counter()`` microseconds relative to the
 tracer's epoch, so spans from every thread share one monotonic clock.
-Two ways to record a span:
+A span is a context manager that times its body.  Code that keeps its
+own perf_counter stamps for its stats (the engines' RoundStats /
+StepStats / Request accounting) takes them inside the span and hands
+them over with :meth:`Span.stamp`, so the timeline and the stats views
+can never disagree about a duration.
 
-* ``with tracer.span("serve.prefill", slot=3): …`` — context manager,
-  times the body;
-* ``tracer.complete("serve.decode", t0, t1, slots=[0, 2])`` — adopt an
-  existing pair of perf_counter stamps.  The engines already bracket
-  their device dispatches with perf_counter for the RoundStats/StepStats
-  accounting; ``complete`` turns those SAME stamps into trace events, so
-  the timeline and the stats views can never disagree about a duration.
+A span may also enter a ``jax.profiler.TraceAnnotation`` of the same
+name (``annotate=True``): the span then lies on the profiler's host
+plane, on the clock of the device trace, whether or not the Chrome
+tracer records it (``tracer=None``).
 
 ``tid`` defaults to the recording thread's ident; slot-scoped serving
 spans override it with the slot index so Perfetto renders one lane per
@@ -31,7 +33,9 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Tracer", "NULL_SPAN"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "Span", "NULL_SPAN"]
 
 
 class _NullSpan:
@@ -46,28 +50,65 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def stamp(self, t0_s=None, t1_s=None) -> None: ...
+
+    def set(self, **args) -> None: ...
+
 
 NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_tid", "_t0")
+def _scalar(args: Dict[str, Any]) -> Dict[str, Any]:
+    """Profiler metadata takes numbers and strings; the rest as text."""
+    return {k: v if isinstance(v, (int, float, str)) else str(v)
+            for k, v in args.items()}
 
-    def __init__(self, tracer: "Tracer", name: str, tid: Optional[int],
-                 args: Dict[str, Any]):
+
+class Span:
+    """One span: a Chrome event when ``tracer`` is given, a profiler
+    annotation when ``annotate`` is set."""
+
+    __slots__ = ("_tracer", "_name", "_alias", "_args", "_tid", "_ann",
+                 "t0", "t1")
+
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 tid: Optional[int], args: Dict[str, Any], *,
+                 annotate: bool = False, alias: Optional[str] = None):
         self._tracer = tracer
         self._name = name
+        self._alias = alias
         self._args = args
         self._tid = tid
-        self._t0 = 0.0
+        self._ann = TraceAnnotation(name, **_scalar(args)) if annotate \
+            else None
+        self.t0 = self.t1 = None
+
+    def stamp(self, t0_s: float, t1_s: float) -> None:
+        """Adopt the caller's own perf_counter stamps for the Chrome event
+        (taken inside the span; the profiler keeps its own clock)."""
+        self.t0, self.t1 = t0_s, t1_s
+
+    def set(self, **args) -> None:
+        """Arguments known only inside the body."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**_scalar(args))
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.complete(self._name, self._t0, time.perf_counter(),
-                              tid=self._tid, **self._args)
+        t1 = time.perf_counter() if self.t1 is None else self.t1
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._tracer is not None:
+            for name in (self._name, self._alias):
+                if name is not None:
+                    self._tracer.complete(name, self.t0, t1, tid=self._tid,
+                                          **self._args)
         return False
 
 
@@ -80,9 +121,11 @@ class Tracer:
     def _us(self, t_s: float) -> float:
         return (t_s - self.epoch) * 1e6
 
-    def span(self, name: str, *, tid: Optional[int] = None, **args):
-        """Context manager timing its body into one complete event."""
-        return _Span(self, name, tid, args)
+    def span(self, name: str, *, tid: Optional[int] = None,
+             annotate: bool = False, alias: Optional[str] = None, **args):
+        """Context manager timing its body into one complete event (two,
+        under ``name`` and ``alias``, when an alias is given)."""
+        return Span(self, name, tid, args, annotate=annotate, alias=alias)
 
     def complete(self, name: str, t0_s: float, t1_s: float, *,
                  tid: Optional[int] = None, **args) -> None:

@@ -141,68 +141,70 @@ def execute_plan(plan: QuantPlan,
         nonlocal retries
         dev = devs[task_idx % len(devs)] if devs else None
         pol = dataclasses.replace(tmpl)
-        t0 = time.perf_counter()
-        while True:
-            try:
-                if dev is None:
-                    q = quantize_at_rate(
-                        weights[entry.name], stats[entry.name],
-                        float(entry.execution_bits), damp=damp, seed=seed,
-                        **(quantize_kwargs or {}))
-                else:
-                    with jax.default_device(dev):
+        dev_label = str(dev) if dev is not None else "default"
+        with obs.span("plan.task", matrix=entry.name, device=dev_label,
+                      bits=float(entry.execution_bits)) as sp:
+            t0 = time.perf_counter()
+            while True:
+                try:
+                    if dev is None:
                         q = quantize_at_rate(
                             weights[entry.name], stats[entry.name],
                             float(entry.execution_bits), damp=damp,
                             seed=seed, **(quantize_kwargs or {}))
-                break
-            except Exception:
-                delay = pol.next_delay()
-                if delay is None:
-                    raise
-                with retry_lock:
-                    retries += 1
-                obs.counter("repro_plan_retries_total").inc()
-                time.sleep(delay)
-        t1 = time.perf_counter()
-        dev_label = str(dev) if dev is not None else "default"
+                    else:
+                        with jax.default_device(dev):
+                            q = quantize_at_rate(
+                                weights[entry.name], stats[entry.name],
+                                float(entry.execution_bits), damp=damp,
+                                seed=seed, **(quantize_kwargs or {}))
+                    break
+                except Exception:
+                    delay = pol.next_delay()
+                    if delay is None:
+                        raise
+                    with retry_lock:
+                        retries += 1
+                    obs.counter("repro_plan_retries_total").inc()
+                    time.sleep(delay)
+            t1 = time.perf_counter()
+            sp.stamp(t0, t1)
         if obs.enabled():
-            obs.complete("plan.task", t0, t1, matrix=entry.name,
-                         device=dev_label,
-                         bits=float(entry.execution_bits))
             obs.counter("repro_plan_tasks_total").inc()
             obs.histogram("repro_plan_task_seconds").observe(t1 - t0)
         return (entry.name, q, t1 - t0, dev_label)
 
-    t_start = time.perf_counter()
     task_s: Dict[str, float] = {}
     device_of: Dict[str, str] = {}
-    pool = ThreadPoolExecutor(max_workers=n_workers) if n_workers > 1 \
-        else None
-    try:
-        done = (pool.map(run_one, range(len(order)), order) if pool
-                else (run_one(i, e) for i, e in enumerate(order)))
-        # consume lazily: the heartbeat/straggler feed advances as tasks
-        # complete (in submission order), not only after the whole pool
-        # drains — an external watchdog sees live progress mid-execution
-        for k, (name, q, dt, dev) in enumerate(done):
-            results[name] = q
-            task_s[name] = dt
-            device_of[name] = dev
-            monitor.observe(dev, dt)
-            if heartbeat is not None:
-                heartbeat.beat(k + 1)
-    finally:
-        if pool is not None:
-            pool.shutdown(wait=True)
-    t_done = time.perf_counter()
+    with obs.span("plan.execute", n_workers=n_workers,
+                  tasks=len(order)) as sp:
+        t_start = time.perf_counter()
+        pool = ThreadPoolExecutor(max_workers=n_workers) if n_workers > 1 \
+            else None
+        try:
+            done = (pool.map(run_one, range(len(order)), order) if pool
+                    else (run_one(i, e) for i, e in enumerate(order)))
+            # consume lazily: the heartbeat/straggler feed advances as
+            # tasks complete (in submission order), not only after the
+            # whole pool drains — an external watchdog sees live progress
+            # mid-execution
+            for k, (name, q, dt, dev) in enumerate(done):
+                results[name] = q
+                task_s[name] = dt
+                device_of[name] = dev
+                monitor.observe(dev, dt)
+                if heartbeat is not None:
+                    heartbeat.beat(k + 1)
+        finally:
+            if pool is not None:
+                pool.shutdown(wait=True)
+        t_done = time.perf_counter()
+        sp.stamp(t_start, t_done)
+        sp.set(retries=retries)
     wall = t_done - t_start
     stragglers = monitor.stragglers()
-    if obs.enabled():
-        obs.complete("plan.execute", t_start, t_done, n_workers=n_workers,
-                     tasks=len(order), retries=retries)
-        if stragglers:
-            obs.counter("repro_plan_stragglers_total").inc(len(stragglers))
+    if obs.enabled() and stragglers:
+        obs.counter("repro_plan_stragglers_total").inc(len(stragglers))
 
     for e in entries:
         q = results[e.name]

@@ -42,21 +42,26 @@ land in ``engine.round_stats`` (static) / ``engine.step_stats``
 (continuous); ``prefill_s`` is device wall-clock up to the last prefill
 logits being ready — the host-side argmax transfer is decode-side.
 
-Observability (DESIGN.md §11): when ``repro.obs`` is enabled the engines
-publish the SAME perf_counter stamps that back RoundStats/StepStats/
-Request into the shared registry and tracer — the dataclasses stay the
-per-round/per-request views, the registry is the aggregation point.
-Request lifecycle lands as trace instants (``serve.request.arrival`` /
-``first_token`` / ``finish``) plus ``repro_serve_ttft_seconds`` /
-``repro_serve_tpot_seconds`` histograms; each prefill/decode region
-becomes a ``serve.prefill`` / ``serve.decode`` span (continuous
-admissions additionally get per-slot ``serve.admit`` spans on slot-
-numbered trace lanes); queue depth and slot occupancy are gauges, and
-admissions/evictions/tokens are counters.  Every device dispatch also
-feeds the modeled per-format HBM weight traffic
-(``repro_kernel_hbm_bytes_total`` via kernels.dequant.ops.record_weight_
-traffic — reconciled against check_bytes accounting in CI).  With obs
-disabled (the default) every hook is a no-op behind one boolean check:
+Observability (DESIGN.md §11): the engines open ``repro.obs`` spans
+around their host work.  While a JAX profiler session collects, every
+span is a ``TraceAnnotation`` on the profiler's clock, so a device trace
+can charge its idle gaps to the span the host was in.  The continuous
+engine's tree is ``serve.step`` ⊃ ``serve.admit`` (one burst) ⊃
+{``serve.admit.prefill``, ``.tail``, ``.graft``, ``.first_token``} and
+``serve.step`` ⊃ ``serve.decode`` ⊃ {``.dispatch``, ``.wait``, ``.sync``,
+``.commit``}; the static engine opens ``serve.prefill`` and
+``serve.decode`` per round.  The jitted programs are named
+(``serve_decode_step``, ``serve_prefill_chunk``, ``serve_init_cache``,
+``serve_admit_row``, ``cache_write_slot``, ``cache_reset_slot``), so each
+device op's module says which program it belongs to.  When ``repro.obs``
+is enabled the same spans become Chrome events that adopt the SAME
+perf_counter stamps that back RoundStats/StepStats/Request (per-row
+admission spans on slot-numbered lanes), request lifecycle lands as
+trace instants (``serve.request.arrival`` / ``first_token`` /
+``finish``) plus ``repro_serve_ttft_seconds`` /
+``repro_serve_tpot_seconds`` histograms, queue depth and slot occupancy
+are gauges, and admissions/evictions/tokens are counters.  With obs
+disabled and no profiler session every hook is a no-op behind one check:
 token streams and stats are byte-identical either way (asserted in
 tests/test_obs_integration.py).
 
@@ -104,8 +109,6 @@ import numpy as np
 
 from repro import chaos, obs
 from repro.configs.base import ArchConfig
-from repro.kernels.dequant.ops import (record_weight_traffic,
-                                       weight_format_bytes)
 from repro.models import (cache_reset_slot, cache_write_slot, decode_chunk,
                           decode_step, init_cache)
 from repro.quant import leaf_format_histogram, qweight_bytes
@@ -127,6 +130,7 @@ class Request:
     done: bool = False
     # latency accounting (perf_counter seconds; stamped by the engines)
     arrival_s: Optional[float] = None      # set by submit() if unset
+    admitted_s: Optional[float] = None     # start of the admitting burst
     first_token_s: Optional[float] = None  # first output token materialized
     finish_s: Optional[float] = None       # budget filled
     # resilience (DESIGN.md §12)
@@ -206,14 +210,23 @@ def _run_prefill(decode_fn, decode_chunk_fn, params, cache,
     return logits, cache, calls
 
 
+def _serving_programs(cfg: ArchConfig):
+    """The jitted decode step and prefill chunk, named so that each device
+    op's module in a trace says which of the two it belongs to."""
+    def serve_decode_step(params, cache, tok):
+        return decode_step(cfg, params, cache, tok)
+
+    def serve_prefill_chunk(params, cache, toks):
+        return decode_chunk(cfg, params, cache, toks)
+
+    return jax.jit(serve_decode_step), jax.jit(serve_prefill_chunk)
+
+
 class _EngineBase:
     """Shared observability + resilience plumbing (DESIGN.md §11/§12).
 
     All obs hooks are no-ops behind one ``obs.enabled()`` check, so the
     disabled (default) path costs a boolean test — never a dict walk.
-    ``_format_bytes`` lazily caches the param tree's per-format stored
-    bytes (quant.leaf_inventory grouping) so each device dispatch can be
-    charged its modeled HBM weight read.
 
     Resilience state is initialized by ``_init_resilience`` (called by
     both constructors, with None when disabled); every resilience branch
@@ -221,12 +234,6 @@ class _EngineBase:
     """
 
     _obs_engine = "?"
-    _fmt_bytes = None
-
-    def _format_bytes(self):
-        if self._fmt_bytes is None:
-            self._fmt_bytes = weight_format_bytes(self.params)
-        return self._fmt_bytes
 
     def prefill_logits(self, prompts: np.ndarray):
         """Last-token logits of equal-length ``prompts`` (B, S) through
@@ -378,13 +385,10 @@ class _EngineBase:
         corrupted = self._guard.verify(self.params)
         if not corrupted:
             return
-        t0 = time.perf_counter()
-        self.params = self._guard.heal(self.params, corrupted)
-        self._fmt_bytes = None      # new tree object (bytes unchanged)
-        t1 = time.perf_counter()
+        with obs.span("resilience.heal", engine=self._obs_engine,
+                      paths=list(corrupted)):
+            self.params = self._guard.heal(self.params, corrupted)
         if obs.enabled():
-            obs.complete("resilience.heal", t0, t1, engine=self._obs_engine,
-                         paths=list(corrupted))
             obs.counter("repro_serve_integrity_corrupt_total",
                         engine=self._obs_engine).inc(len(corrupted))
             obs.counter("repro_serve_integrity_healed_total",
@@ -460,7 +464,6 @@ class _EngineBase:
         expected-distortion entries for the old codes drop.
         """
         self.params = tree
-        self._fmt_bytes = None
         self.weight_bytes, self.weight_bytes_bf16 = qweight_bytes(tree)
         self.weight_formats = leaf_format_histogram(tree)
         if self._guard is not None:
@@ -541,11 +544,9 @@ class ServeEngine(_EngineBase):
         # HBM bytes vs bf16 and the per-leaf format mix of this engine
         self.weight_bytes, self.weight_bytes_bf16 = qweight_bytes(self.params)
         self.weight_formats = leaf_format_histogram(self.params)
-        self._decode = config.decode_fn or jax.jit(
-            lambda params, cache, tok: decode_step(cfg, params, cache, tok))
-        self._decode_chunk = config.decode_chunk_fn or jax.jit(
-            lambda params, cache, toks: decode_chunk(cfg, params, cache,
-                                                     toks))
+        step_fn, chunk_fn = _serving_programs(cfg)
+        self._decode = config.decode_fn or step_fn
+        self._decode_chunk = config.decode_chunk_fn or chunk_fn
 
     def submit(self, req: Request) -> bool:
         if not self._submit_common(req):
@@ -597,37 +598,46 @@ class ServeEngine(_EngineBase):
         cache = init_cache(self.cfg, b, self.max_len, self.cache_dtype)
 
         prompts = np.stack([r.prompt for r in batch]).astype(np.int32)
-        t0 = self._now()
-        logits, cache, prefill_calls = self._prefill(cache, prompts)
-        jax.block_until_ready(logits)
-        t1 = self._now()           # BEFORE the host argmax transfer: the
-        # transfer + argmax consume the first generated token, so they are
-        # decode-side work, not prompt work.
-        last = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
-        # Budget-exact generation: consume `last` first, decode only while
-        # some request still has budget left.  Each slot stops at exactly
-        # its own max_new_tokens (mixed budgets share the batch; finished
-        # slots keep stepping their cache but emit nothing), and the number
-        # of decode calls is exactly max(budgets) - 1 — no trailing decode
-        # whose logits nobody consumes.
-        decode_steps = 0
-        while True:
-            t_tok = self._now()
-            for i, r in enumerate(batch):
-                if len(r.out_tokens) < r.max_new_tokens:
-                    r.out_tokens.append(int(last[i]))
-                    if r.first_token_s is None:
-                        r.first_token_s = t_tok
-                    if len(r.out_tokens) >= r.max_new_tokens:
-                        r.finish_s = t_tok
-            if all(len(r.out_tokens) >= r.max_new_tokens for r in batch):
-                break
-            assert decode_steps < budget, "decode loop exceeded round budget"
-            decode_steps += 1
-            logits, cache = self._decode(self.params, cache,
-                                         jnp.asarray(last[:, None]))
+        with obs.span("serve.prefill", engine="static", batch=b) as sp:
+            t0 = self._now()
+            logits, cache, prefill_calls = self._prefill(cache, prompts)
+            jax.block_until_ready(logits)
+            t1 = self._now()       # BEFORE the host argmax transfer: the
+            # transfer + argmax consume the first generated token, so they
+            # are decode-side work, not prompt work.
+            sp.stamp(t0, t1)
+            sp.set(calls=prefill_calls)
+        with obs.span("serve.decode", engine="static", batch=b) as sp:
             last = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
-        t2 = self._now()
+            # Budget-exact generation: consume `last` first, decode only
+            # while some request still has budget left.  Each slot stops at
+            # exactly its own max_new_tokens (mixed budgets share the batch;
+            # finished slots keep stepping their cache but emit nothing),
+            # and the number of decode calls is exactly max(budgets) - 1 —
+            # no trailing decode whose logits nobody consumes.
+            decode_steps = 0
+            while True:
+                t_tok = self._now()
+                for i, r in enumerate(batch):
+                    if len(r.out_tokens) < r.max_new_tokens:
+                        r.out_tokens.append(int(last[i]))
+                        if r.first_token_s is None:
+                            r.first_token_s = t_tok
+                        if len(r.out_tokens) >= r.max_new_tokens:
+                            r.finish_s = t_tok
+                if all(len(r.out_tokens) >= r.max_new_tokens
+                       for r in batch):
+                    break
+                assert decode_steps < budget, \
+                    "decode loop exceeded round budget"
+                decode_steps += 1
+                logits, cache = self._decode(self.params, cache,
+                                             jnp.asarray(last[:, None]))
+                last = np.argmax(np.asarray(logits),
+                                 axis=-1).astype(np.int32)
+            t2 = self._now()
+            sp.stamp(t1, t2)
+            sp.set(calls=decode_steps)
         st = RoundStats(
             batch=b, prompt_len=plen, prefill_calls=prefill_calls,
             prefill_s=t1 - t0, decode_calls=decode_steps, decode_s=t2 - t1,
@@ -636,11 +646,6 @@ class ServeEngine(_EngineBase):
             tpot_s=[r.tpot_s for r in batch if r.tpot_s is not None])
         self.round_stats.append(st)
         if obs.enabled():
-            # registry/tracer views of the SAME stamps RoundStats carries
-            obs.complete("serve.prefill", t0, t1, engine="static",
-                         batch=b, calls=st.prefill_calls)
-            obs.complete("serve.decode", t1, t2, engine="static",
-                         batch=b, calls=st.decode_calls)
             obs.counter("repro_serve_rounds_total").inc()
             obs.counter("repro_serve_admitted_total",
                         engine="static").inc(b)
@@ -650,8 +655,6 @@ class ServeEngine(_EngineBase):
                       engine="static").set(len(self.queue))
             for r in batch:
                 self._obs_request_done(r)
-            record_weight_traffic(self._format_bytes(),
-                                  st.prefill_calls + st.decode_calls)
         for r in batch:
             r.done = True
         self._observe_step_time(t2 - t0)
@@ -714,15 +717,30 @@ class ContinuousEngine(_EngineBase):
         self._init_resilience(config.resilience)  # may swap params to rung 0
         self.weight_bytes, self.weight_bytes_bf16 = qweight_bytes(self.params)
         self.weight_formats = leaf_format_histogram(self.params)
-        self._decode = config.decode_fn or jax.jit(
-            lambda params, cache, tok: decode_step(cfg, params, cache, tok))
-        self._decode_chunk = config.decode_chunk_fn or jax.jit(
-            lambda params, cache, toks: decode_chunk(cfg, params, cache,
-                                                     toks))
+        step_fn, chunk_fn = _serving_programs(cfg)
+        self._decode = config.decode_fn or step_fn
+        self._decode_chunk = config.decode_chunk_fn or chunk_fn
         # the engine is the sole owner of the slot cache, so graft/reset can
         # donate it — in-place row updates instead of a full cache copy
         self._write_slot = jax.jit(cache_write_slot, donate_argnums=(0,))
         self._reset_slot = jax.jit(cache_reset_slot, donate_argnums=(0,))
+        max_len, cache_dtype = self.max_len, self.cache_dtype
+
+        # an admission burst's sub-cache and its per-row slices, named
+        # programs so that their device time is attributable in a trace
+        def serve_init_cache(g):
+            return init_cache(cfg, g, max_len, cache_dtype)
+
+        def serve_admit_row(sub, logits, i):
+            with jax.named_scope("kv_cache"):
+                kv_i, ex_i = jax.tree.map(
+                    lambda t: jax.lax.dynamic_slice_in_dim(t, i, 1, axis=1),
+                    (sub.kv, sub.extras))
+            return (sub._replace(kv=kv_i, extras=ex_i),
+                    jax.lax.dynamic_slice_in_dim(logits, i, 1))
+
+        self._init_sub = jax.jit(serve_init_cache, static_argnums=0)
+        self._admit_row = jax.jit(serve_admit_row)
         self.cache = init_cache(cfg, self.n_slots, self.max_len,
                                 self.cache_dtype, per_slot=True)
         self.slots: List[Optional[Request]] = [None] * self.n_slots
@@ -758,71 +776,84 @@ class ContinuousEngine(_EngineBase):
         row.  decode_chunk is row-independent and bit-exact vs per-token,
         so the grouped prefill changes no request's stream (fuzzed in
         tests/test_continuous_batching.py).
+
+        ``prefill_s`` bills the burst from before its sub-cache is
+        allocated (``serve_init_cache``) to logits-ready, and each tail
+        from its first dispatch to logits-ready: the allocation counts as
+        prefill, the row slices, grafts and host argmaxes do not.  In a
+        device trace the allocation shows apart, as the ``kv_cache`` op of
+        the ``serve_init_cache`` module inside ``serve.admit.prefill``.
         """
         g = len(pairs)
         reqs = [r for _, r in pairs]
+        slots = [s for s, _ in pairs]
         common = min(len(r.prompt) for r in reqs)
-        # prefill_s bills ONLY the prefill device work (same contract as
-        # RoundStats.prefill_s): each timed region ends at logits-ready,
-        # before the host argmax transfer / graft / bookkeeping
-        t0 = self._now()
-        sub = init_cache(self.cfg, g, self.max_len, self.cache_dtype)
-        toks = np.stack([np.asarray(r.prompt[:common], np.int32)
-                         for r in reqs])
-        logits, sub, calls = _run_prefill(
-            self._decode, self._decode_chunk, self.params, sub, toks,
-            self.prefill_chunk)
-        jax.block_until_ready(logits)
-        t1 = self._now()
-        self.prefill_s += t1 - t0
-        obs.complete("serve.prefill", t0, t1, engine="continuous",
-                     slots=[s for s, _ in pairs], calls=calls,
-                     common_len=common)
-        for i, (slot, req) in enumerate(pairs):
-            if g == 1:
-                sub_i, log_i = sub, logits
-            else:
-                kv_i, ex_i = jax.tree.map(lambda t: t[:, i:i + 1],
-                                          (sub.kv, sub.extras))
-                sub_i = sub._replace(kv=kv_i, extras=ex_i)
-                log_i = logits[i:i + 1]
-            tail = np.asarray(req.prompt[common:], np.int32)
-            if tail.size:
-                t_tail = self._now()
-                log_i, sub_i, c_tail = _run_prefill(
-                    self._decode, self._decode_chunk, self.params, sub_i,
-                    tail[None, :], self.prefill_chunk)
-                jax.block_until_ready(log_i)
-                t_tail_end = self._now()
-                self.prefill_s += t_tail_end - t_tail
-                obs.complete("serve.prefill", t_tail, t_tail_end,
-                             engine="continuous", slot=slot, rid=req.rid,
-                             calls=c_tail)
-                calls += c_tail
-            first = int(np.argmax(np.asarray(log_i)[0]))
-            self.cache = self._write_slot(self.cache, sub_i,
-                                          jnp.asarray(slot, jnp.int32))
-            t_tok = self._now()
-            req.first_token_s = t_tok
-            req.out_tokens.append(first)
-            self.slots[slot] = req
-            self._last[slot] = first
-            if obs.enabled():
-                # per-slot admission lane: burst prefill + this row's graft
-                obs.complete("serve.admit", t0, t_tok, tid=slot, slot=slot,
-                             engine="continuous", rid=req.rid,
-                             prompt_len=len(req.prompt))
-                obs.instant("serve.request.first_token", rid=req.rid,
-                            slot=slot, engine="continuous")
-            if len(req.out_tokens) >= req.max_new_tokens:
-                self._finish(slot, req, t_tok, finished)
+        with obs.span("serve.admit", engine="continuous", g=g, slots=slots,
+                      common_len=common) as admit_span:
+            with obs.span("serve.admit.prefill", alias="serve.prefill",
+                          engine="continuous", slots=slots,
+                          common_len=common) as sp:
+                t0 = self._now()
+                for r in reqs:
+                    r.admitted_s = t0
+                sub = self._init_sub(g)
+                toks = np.stack([np.asarray(r.prompt[:common], np.int32)
+                                 for r in reqs])
+                logits, sub, calls = _run_prefill(
+                    self._decode, self._decode_chunk, self.params, sub, toks,
+                    self.prefill_chunk)
+                jax.block_until_ready(logits)
+                t1 = self._now()
+                sp.stamp(t0, t1)
+                sp.set(calls=calls)
+            self.prefill_s += t1 - t0
+            for i, (slot, req) in enumerate(pairs):
+                if g == 1:
+                    sub_i, log_i = sub, logits
+                else:
+                    with obs.span("serve.admit.graft", tid=slot, slot=slot,
+                                  rid=req.rid):
+                        sub_i, log_i = self._admit_row(sub, logits,
+                                                       np.int32(i))
+                tail = np.asarray(req.prompt[common:], np.int32)
+                if tail.size:
+                    with obs.span("serve.admit.tail", alias="serve.prefill",
+                                  tid=slot, engine="continuous", slot=slot,
+                                  rid=req.rid) as sp:
+                        t_tail = self._now()
+                        log_i, sub_i, c_tail = _run_prefill(
+                            self._decode, self._decode_chunk, self.params,
+                            sub_i, tail[None, :], self.prefill_chunk)
+                        jax.block_until_ready(log_i)
+                        t_tail_end = self._now()
+                        sp.stamp(t_tail, t_tail_end)
+                        sp.set(calls=c_tail)
+                    self.prefill_s += t_tail_end - t_tail
+                    calls += c_tail
+                with obs.span("serve.admit.first_token", tid=slot,
+                              slot=slot, rid=req.rid):
+                    first = int(np.argmax(np.asarray(log_i)[0]))
+                with obs.span("serve.admit.graft", tid=slot, slot=slot,
+                              rid=req.rid):
+                    self.cache = self._write_slot(
+                        self.cache, sub_i, jnp.asarray(slot, jnp.int32))
+                t_tok = self._now()
+                req.first_token_s = t_tok
+                req.out_tokens.append(first)
+                self.slots[slot] = req
+                self._last[slot] = first
+                if obs.enabled():
+                    obs.instant("serve.request.first_token", rid=req.rid,
+                                slot=slot, engine="continuous")
+                if len(req.out_tokens) >= req.max_new_tokens:
+                    self._finish(slot, req, t_tok, finished)
+            admit_span.stamp(t0, t_tok)
         self.prefill_calls += calls
         if obs.enabled():
             obs.counter("repro_serve_admitted_total",
                         engine="continuous").inc(g)
             obs.counter("repro_serve_tokens_total",
                         engine="continuous").inc(g)
-            record_weight_traffic(self._format_bytes(), calls)
 
     def _finish(self, slot: int, req: Request, t: float,
                 finished: List[Request]) -> None:
@@ -891,89 +922,104 @@ class ContinuousEngine(_EngineBase):
         transient admission/decode faults, and snapshots periodically.
         """
         finished: List[Request] = []
-        self._tick += 1
-        self._apply_pending_swap()      # step boundary: staged tree lands
-        t0 = self._now()
-        if chaos.enabled():
-            chaos.fire("serve.step", engine=self)
-        if self.resilience is not None:
-            self._verify_integrity()
-            self._expire_queue()
-            self._expire_slots()
-            self._maybe_degrade()
-        pairs = []
-        while self.queue and None in self.slots:
-            slot = self.slots.index(None)
-            req = self.queue.popleft()
-            self.slots[slot] = req          # reserve before the next index()
-            pairs.append((slot, req))
-        admitted = len(pairs)
-        if pairs:
-            try:
-                self._retry("serve.admit",
-                            lambda: self._admit_burst(pairs, finished))
-            except BaseException:
-                # retry budget exhausted (or non-transient): un-reserve the
-                # untouched requests and put them back at the FRONT of the
-                # queue in arrival order, so nothing is silently lost.
-                # (injection fires before _admit_many mutates anything, so
-                # an injected-fault unwind always finds them untouched)
-                for slot, req in pairs:
-                    if self.slots[slot] is req and not req.out_tokens:
-                        self.slots[slot] = None
-                for slot, req in reversed(pairs):
-                    if not req.out_tokens and not req.dropped:
-                        self.queue.appendleft(req)
-                raise
-        active = [i for i, r in enumerate(self.slots) if r is not None]
-        decoded = 0
-        if active:
+        with obs.span("serve.step", engine="continuous") as step_span:
+            self._tick += 1
+            self._apply_pending_swap()  # step boundary: staged tree lands
+            t0 = self._now()
+            if chaos.enabled():
+                chaos.fire("serve.step", engine=self)
+            if self.resilience is not None:
+                self._verify_integrity()
+                self._expire_queue()
+                self._expire_slots()
+                self._maybe_degrade()
+            pairs = []
+            while self.queue and None in self.slots:
+                slot = self.slots.index(None)
+                req = self.queue.popleft()
+                self.slots[slot] = req      # reserve before the next index()
+                pairs.append((slot, req))
+            admitted = len(pairs)
+            if pairs:
+                try:
+                    self._retry("serve.admit",
+                                lambda: self._admit_burst(pairs, finished))
+                except BaseException:
+                    # retry budget exhausted (or non-transient): un-reserve
+                    # the untouched requests and put them back at the FRONT
+                    # of the queue in arrival order, so nothing is silently
+                    # lost.  (injection fires before _admit_many mutates
+                    # anything, so an injected-fault unwind always finds
+                    # them untouched)
+                    for slot, req in pairs:
+                        if self.slots[slot] is req and not req.out_tokens:
+                            self.slots[slot] = None
+                    for slot, req in reversed(pairs):
+                        if not req.out_tokens and not req.dropped:
+                            self.queue.appendleft(req)
+                    raise
+            active = [i for i, r in enumerate(self.slots) if r is not None]
+            decoded = self._decode_round(active, finished) if active else 0
+            t_end = self._now()
+            step_span.stamp(t0, t_end)
+            step_span.set(active=len(active), admitted=admitted,
+                          finished=len(finished))
+            self.step_stats.append(StepStats(
+                active=len(active), admitted=admitted,
+                finished=len(finished), new_tokens=admitted + decoded,
+                step_s=t_end - t0))
+            if obs.enabled():
+                obs.counter("repro_serve_tokens_total",
+                            engine="continuous").inc(decoded)
+                obs.gauge("repro_serve_slots_active",
+                          engine="continuous").set(self.active_slots)
+                obs.gauge("repro_serve_queue_depth",
+                          engine="continuous").set(len(self.queue))
+            self._observe_step_time(t_end - t0)
+            if self._quality is not None and obs.enabled():
+                # quality observatory sampling (DESIGN.md §14) — reached
+                # only with obs on AND a monitor attached, so the default
+                # serving path stays byte-identical
+                self._quality.observe_step(self, t_end - t0, self.slots)
+            self._poll_requant()
+            res = self.resilience
+            if (res is not None and res.snapshot_every and res.snapshot_dir
+                    and self._tick % res.snapshot_every == 0):
+                self.snapshot(res.snapshot_dir)
+        return finished
+
+    def _decode_round(self, active: List[int],
+                      finished: List[Request]) -> int:
+        """One lockstep decode over every slot: dispatch, wait, the logits
+        to the host and their argmax, then the token appends and
+        evictions.  ``decode_s`` bills dispatch to argmax.  Returns the
+        tokens emitted."""
+        with obs.span("serve.decode", engine="continuous",
+                      slots=active) as sp:
             td = self._now()
-            logits, new_cache = self._retry("serve.decode",
-                                            self._decode_dispatch)
+            with obs.span("serve.decode.dispatch"):
+                logits, new_cache = self._retry("serve.decode",
+                                                self._decode_dispatch)
+                # the logits leave for the host as soon as the step ends,
+                # not after the wait below has woken this thread
+                logits.copy_to_host_async()
             self.cache = new_cache
-            last = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
+            with obs.span("serve.decode.wait"):
+                jax.block_until_ready(logits)
+            with obs.span("serve.decode.sync"):
+                last = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
             t_tok = self._now()
+            sp.stamp(td, t_tok)
             self.decode_calls += 1
             self.decode_s += t_tok - td
-            obs.complete("serve.decode", td, t_tok, engine="continuous",
-                         slots=active)
-            for i in active:
-                r = self.slots[i]
-                r.out_tokens.append(int(last[i]))
-                self._last[i] = last[i]
-                decoded += 1
-                if len(r.out_tokens) >= r.max_new_tokens:
-                    self._finish(i, r, t_tok, finished)
-        t_end = self._now()
-        self.step_stats.append(StepStats(
-            active=len(active), admitted=admitted, finished=len(finished),
-            new_tokens=admitted + decoded,
-            step_s=t_end - t0))
-        if obs.enabled():
-            obs.complete("serve.step", t0, t_end, engine="continuous",
-                         active=len(active), admitted=admitted,
-                         finished=len(finished))
-            obs.counter("repro_serve_tokens_total",
-                        engine="continuous").inc(decoded)
-            obs.gauge("repro_serve_slots_active",
-                      engine="continuous").set(self.active_slots)
-            obs.gauge("repro_serve_queue_depth",
-                      engine="continuous").set(len(self.queue))
-            if active:
-                record_weight_traffic(self._format_bytes(), 1)
-        self._observe_step_time(t_end - t0)
-        if self._quality is not None and obs.enabled():
-            # quality observatory sampling (DESIGN.md §14) — reached only
-            # with obs on AND a monitor attached, so the default serving
-            # path stays byte-identical
-            self._quality.observe_step(self, t_end - t0, self.slots)
-        self._poll_requant()
-        res = self.resilience
-        if (res is not None and res.snapshot_every and res.snapshot_dir
-                and self._tick % res.snapshot_every == 0):
-            self.snapshot(res.snapshot_dir)
-        return finished
+            with obs.span("serve.decode.commit"):
+                for i in active:
+                    r = self.slots[i]
+                    r.out_tokens.append(int(last[i]))
+                    self._last[i] = last[i]
+                    if len(r.out_tokens) >= r.max_new_tokens:
+                        self._finish(i, r, t_tok, finished)
+        return len(active)
 
     # -- snapshot / resume (DESIGN.md §12) ----------------------------------
 
@@ -1013,13 +1059,12 @@ class ContinuousEngine(_EngineBase):
                       for r in self.slots],
             "queue": [self._req_record(r) for r in self.queue],
         }
-        t0 = time.perf_counter()
-        path = save_checkpoint(ckpt_dir, self._tick, state, keep=keep,
-                               extra_meta=meta)
-        t1 = time.perf_counter()
+        with obs.span("resilience.snapshot", engine="continuous",
+                      step=self._tick) as sp:
+            path = save_checkpoint(ckpt_dir, self._tick, state, keep=keep,
+                                   extra_meta=meta)
+            sp.set(path=str(path))
         if obs.enabled():
-            obs.complete("resilience.snapshot", t0, t1, engine="continuous",
-                         step=self._tick, path=str(path))
             obs.counter("repro_serve_snapshots_total",
                         engine="continuous").inc()
         return str(path)

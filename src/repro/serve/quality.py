@@ -40,7 +40,6 @@ tests/test_obs_integration.py and tests/test_quality.py).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -149,15 +148,15 @@ class QualityMonitor:
             toks = self._window_tokens(reqs)
             if toks is not None:
                 from repro.quant.calibrate import forward_with_taps
-                t0 = time.perf_counter()
-                logits_fp, taps = forward_with_taps(self.cfg, self.ref, toks)
-                if due_sigma:
-                    self._update_sigma(taps)
-                if due_probe:
-                    self._probe(engine, toks, logits_fp, taps)
-                obs.complete("quality.shadow", t0, time.perf_counter(),
-                             tick=self.tick, rows=int(toks.shape[0]),
-                             sigma=bool(due_sigma), probe=bool(due_probe))
+                with obs.span("quality.shadow", tick=self.tick,
+                              rows=int(toks.shape[0]), sigma=bool(due_sigma),
+                              probe=bool(due_probe)):
+                    logits_fp, taps = forward_with_taps(self.cfg, self.ref,
+                                                        toks)
+                    if due_sigma:
+                        self._update_sigma(taps)
+                    if due_probe:
+                        self._probe(engine, toks, logits_fp, taps)
         if c.slo_every and self.tick % c.slo_every == 0:
             self.slo_rows = evaluate_slos(self.slos)
 

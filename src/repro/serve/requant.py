@@ -216,27 +216,31 @@ class RequantActuator:
 
     def _actuate(self, engine, snaps: Dict[str, SigmaSnapshot]) -> None:
         c = self.config
-        t0 = time.perf_counter()
-        payload_before = {e.name: int(e.payload_bits) for e in self.plan}
+        with obs.span("requant.actuate", tick=engine._tick,
+                      taps=sorted(snaps)) as sp:
+            t0 = time.perf_counter()
+            payload_before = {e.name: int(e.payload_bits) for e in self.plan}
 
-        def work():
-            # the chaos site fires BEFORE any re-plan work, so a retried
-            # actuation replays from the same frozen snapshots and lands
-            # the bit-identical tree (chaos-during-requant test)
-            if chaos.enabled():
-                chaos.fire("requant.execute", engine=engine)
-            return replan_from_sigma(
-                self.cfg, self.ref, self.plan, snaps, damp=c.damp,
-                seed=c.seed, n_workers=c.n_workers,
-                quantize_kwargs=c.quantize_kwargs)
+            def work():
+                # the chaos site fires BEFORE any re-plan work, so a retried
+                # actuation replays from the same frozen snapshots and lands
+                # the bit-identical tree (chaos-during-requant test)
+                if chaos.enabled():
+                    chaos.fire("requant.execute", engine=engine)
+                return replan_from_sigma(
+                    self.cfg, self.ref, self.plan, snaps, damp=c.damp,
+                    seed=c.seed, n_workers=c.n_workers,
+                    quantize_kwargs=c.quantize_kwargs)
 
-        new_plan, tree, _, report, affected = engine._retry(
-            "requant.execute", work)
-        engine.request_swap(tree, reason="requant")
-        self.monitor.rebase_sigma({t: s.sigma for t, s in snaps.items()})
-        plan_before, self.plan = self.plan, new_plan
-        self._cooldown = c.cooldown_steps
-        t1 = time.perf_counter()
+            new_plan, tree, _, report, affected = engine._retry(
+                "requant.execute", work)
+            engine.request_swap(tree, reason="requant")
+            self.monitor.rebase_sigma({t: s.sigma for t, s in snaps.items()})
+            plan_before, self.plan = self.plan, new_plan
+            self._cooldown = c.cooldown_steps
+            t1 = time.perf_counter()
+            sp.stamp(t0, t1)
+            sp.set(matrices=len(affected))
         self.actuations.append({
             "tick": engine._tick,
             # frozen inputs + outputs of the pure re-plan, kept so an
@@ -255,8 +259,6 @@ class RequantActuator:
             "wall_s": t1 - t0,
         })
         if obs.enabled():
-            obs.complete("requant.actuate", t0, t1, tick=engine._tick,
-                         taps=sorted(snaps), matrices=len(affected))
             obs.counter("repro_requant_actuations_total").inc()
             obs.counter("repro_requant_matrices_total").inc(len(affected))
 

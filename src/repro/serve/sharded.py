@@ -42,8 +42,6 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Tuple
 
-import time
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -305,27 +303,25 @@ def build_sharded_decode_fns(cfg, params, mesh, *, axis_name: str = "model"):
                    tok.shape)
             hit = compiled.get(key)
             if hit is None:
-                t0 = time.perf_counter()
-                cspecs, cache_sharded = cache_pspecs(
-                    cache, axis_name=axis_name, shards=shards)
+                # mesh span/metric parity with the single-device engines
+                # (DESIGN.md §14): trace-building cost on a cache miss + a
+                # per-shape compile counter
+                with obs.span("serve.mesh.compile", tag=tag, shards=shards,
+                              tok_shape=list(tok.shape)):
+                    cspecs, cache_sharded = cache_pspecs(
+                        cache, axis_name=axis_name, shards=shards)
 
-                def body(p_, c_, t_):
-                    with manual_axes(axis=axis_name, shards=shards,
-                                     cache_sharded=cache_sharded):
-                        return fn(cfg, p_, c_, t_)
+                    def body(p_, c_, t_):
+                        with manual_axes(axis=axis_name, shards=shards,
+                                         cache_sharded=cache_sharded):
+                            return fn(cfg, p_, c_, t_)
 
-                hit = compiled[key] = jax.jit(jax.shard_map(
-                    body, mesh=mesh,
-                    in_specs=(pspecs, cspecs, P()),
-                    out_specs=(P(), cspecs),
-                    check_vma=False))
+                    hit = compiled[key] = jax.jit(jax.shard_map(
+                        body, mesh=mesh,
+                        in_specs=(pspecs, cspecs, P()),
+                        out_specs=(P(), cspecs),
+                        check_vma=False))
                 if obs.enabled():
-                    # mesh span/metric parity with the single-device
-                    # engines (DESIGN.md §14): trace-building cost on a
-                    # cache miss + a per-shape compile counter
-                    obs.complete("serve.mesh.compile", t0,
-                                 time.perf_counter(), tag=tag,
-                                 shards=shards, tok_shape=list(tok.shape))
                     obs.counter("repro_serve_mesh_compile_total",
                                 tag=tag).inc()
             if obs.enabled():

@@ -67,6 +67,8 @@ def test_packed_kernel_compiles_for_v5e(one_chip, monkeypatch, nbits, k, n):
     text = _compiled_text(monkeypatch, ops._dequant_matmul_packed, args,
                           nbits=nbits)
     assert "tpu_custom_call" in text
+    # the kernel's name is its instruction's, which a device trace shows
+    assert f"%dequant_matmul_packed_int{nbits}" in text
 
 
 @pytest.mark.parametrize("k,n", MLP_SHAPES)
@@ -77,3 +79,4 @@ def test_int8_kernel_compiles_for_v5e(one_chip, monkeypatch, k, n):
             sds((k,), jnp.float32), sds((n,), jnp.float32))
     text = _compiled_text(monkeypatch, ops._dequant_matmul_int8, args)
     assert "tpu_custom_call" in text
+    assert "%dequant_matmul_int8" in text
