@@ -61,9 +61,12 @@ from repro.plan import collect_sigma_x  # noqa: E402
 from repro.quant.calibrate import stats_for_matrix  # noqa: E402
 from repro.quant.pipeline import matrix_tap_map  # noqa: E402
 
-#: kernel vs XLA twin: max|out − ref| / max|ref|.  Codes are exact in any
-#: float format; the bound covers bf16 rounding of x·s in one MXU pass
-#: (2^-9 relative per product), with margin.
+#: kernel vs XLA twin: max|out − ref| / max|ref|.  Codes are exact in bf16
+#: and the kernel carries x·s as three exact bf16 terms, so it holds f32
+#: accuracy (about 2e-7 against a float64 truth on a TPU v5e, int4 at
+#: 1 and 8 rows); the bound also admits one bf16 rounding of x·s (2^-9
+#: relative per product), as an f32 × f32 Mosaic dot at default precision
+#: would make.
 KERNEL_RTOL = 1e-2
 #: bf16 serving vs the f32-activation reference forward: the mean (over
 #: the prompts) relative L2 error of the engine's last-token logits may be

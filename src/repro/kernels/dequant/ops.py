@@ -11,13 +11,14 @@ with the payload nbits read off the shape (core/packing layouts) —
 All three route through the SAME generalized Pallas kernel
 (``dequant_matmul_packed_pallas``), which unpacks in-VMEM and contracts
 plane-by-plane — the full 2/3/4-bit serving ladder runs in-kernel
-(DESIGN.md §8).  This wrapper pads to MXU-aligned block multiples
-(including the ragged-in-features pad columns of any packed payload),
-splits the activation columns into the payload's planar groups,
-dispatches to the Pallas kernels on TPU (or interpret mode when
-requested) and to the XLA reference twins (kernels/dequant/ref.py) on
-CPU, slices the padding off, and applies the sparse escape correction —
-out-of-range codes stored as a COO delta list — outside the kernel.
+(DESIGN.md §8).  This wrapper zero-pads x/s over the ragged in-features
+pad columns of any packed payload, scales the activations once (x·s, as
+three exact bf16 terms), splits them into the payload's planar groups,
+chooses the blocks from the shapes, dispatches to the Pallas kernels on
+TPU (or interpret mode when requested) and to the XLA reference twins
+(kernels/dequant/ref.py) on CPU, slices any row padding off, and applies
+the sparse escape correction — out-of-range codes stored as a COO delta
+list — outside the kernel.
 
 ``dequant_matmul_xla`` is the collective-friendly pure-XLA formulation used
 inside pjit'd serve graphs (the dry-run path): XLA fuses the int8→f32
@@ -26,7 +27,7 @@ advantage that the roofline analysis measures.  The packed XLA siblings
 (``dequant_matmul_packed_xla`` / ``_packed3_xla`` / ``_packed2_xla``) are
 thin aliases of the ref-twin with the payload nbits pinned.
 
-The packed path's padding, splitting and slicing run under the
+The packed path's scaling, splitting, padding and slicing run under the
 ``packed_matmul`` scope, and each Pallas call carries its own kernel
 name, so a device trace tells the kernel's own time and its wrapper's
 pads from the rest of the step (DESIGN.md §11).
@@ -39,8 +40,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from .dequant_matmul import (PLANE_GROUPS, dequant_matmul_packed_pallas,
-                             dequant_matmul_pallas)
+from .dequant_matmul import (PLANE_GROUPS, SPLIT_TERMS,
+                             dequant_matmul_packed_pallas,
+                             dequant_matmul_pallas, packed_blocks, split_bf16)
 from .ref import dequant_matmul_packed_ref, dequant_matmul_ref
 
 __all__ = ["dequant_matmul", "dequant_matmul_packed", "dequant_matmul_xla",
@@ -143,15 +145,14 @@ def _apply_escapes(out, x, col_scale, row_scale, escapes):
 
 
 def dequant_matmul(x, z, col_scale, row_scale, *, escapes=None,
-                   block_m: int = 128, block_n: int = 128,
-                   block_k: int = 512, prefer_pallas: bool = True,
-                   interpret: bool = False):
+                   block_m=None, block_n=None, block_k=None,
+                   prefer_pallas: bool = True, interpret: bool = False):
     """x (m, k) · dequant(z, s, t)ᵀ → (m, n), padding + escapes handled here.
 
     ``z`` int8 (n, k) selects the int8 kernel; a uint8 payload selects the
     packed kernel at the nbits its shape encodes (``payload_nbits``).
     ``escapes`` is an optional COO triple (rows, cols, dvals) applied after
-    the kernel.
+    the kernel.  Block sizes left ``None`` are chosen from the shapes.
     """
     if z.dtype == jnp.uint8:
         return dequant_matmul_packed(
@@ -160,8 +161,9 @@ def dequant_matmul(x, z, col_scale, row_scale, *, escapes=None,
             block_k=block_k, prefer_pallas=prefer_pallas,
             interpret=interpret)
     return _dequant_matmul_int8(
-        x, z, col_scale, row_scale, escapes=escapes, block_m=block_m,
-        block_n=block_n, block_k=block_k, prefer_pallas=prefer_pallas,
+        x, z, col_scale, row_scale, escapes=escapes,
+        block_m=block_m or 128, block_n=block_n or 128,
+        block_k=block_k or 512, prefer_pallas=prefer_pallas,
         interpret=interpret)
 
 
@@ -192,8 +194,8 @@ def _dequant_matmul_int8(x, z, col_scale, row_scale, *, escapes=None,
 
 def dequant_matmul_packed(x, payload, col_scale, row_scale, *,
                           nbits: int = 4, escapes=None,
-                          block_m: int = 128, block_n: int = 128,
-                          block_k: int = 512, prefer_pallas: bool = True,
+                          block_m=None, block_n=None, block_k=None,
+                          prefer_pallas: bool = True,
                           interpret: bool = False):
     """Packed serving matmul: x (m, k) × planar sub-byte payload.
 
@@ -201,8 +203,11 @@ def dequant_matmul_packed(x, payload, col_scale, row_scale, *,
     code 0 (or an arbitrary value — see below), and x / col_scale are
     zero-padded to the packed width G·kg before the planar groups are
     split, so every pad column multiplies an all-zero activation column
-    and contributes nothing.  The same argument covers the block-align
-    padding of the byte axis.
+    and contributes nothing.  The same argument covers a block-align pad
+    of the byte axis, which only explicit blocks or the fallback of
+    ``packed_blocks`` ask for.  Block sizes left ``None`` are chosen from
+    the shapes (``packed_blocks``); ``block_k`` counts columns, G per
+    payload byte.
     """
     return _dequant_matmul_packed(
         x, payload, col_scale, row_scale, nbits=nbits, escapes=escapes,
@@ -215,8 +220,8 @@ def dequant_matmul_packed(x, payload, col_scale, row_scale, *,
                                              "interpret"))
 def _dequant_matmul_packed(x, payload, col_scale, row_scale, *,
                            nbits: int = 4, escapes=None,
-                           block_m: int = 128, block_n: int = 128,
-                           block_k: int = 512, prefer_pallas: bool = True,
+                           block_m=None, block_n=None, block_k=None,
+                           prefer_pallas: bool = True,
                            interpret: bool = False):
     with jax.named_scope("packed_matmul"):
         g = PLANE_GROUPS[nbits]
@@ -228,19 +233,27 @@ def _dequant_matmul_packed(x, payload, col_scale, row_scale, *,
         sp = _pad_to(col_scale, k_packed, 0) if k < k_packed else col_scale
         on_tpu = jax.default_backend() == "tpu"
         if prefer_pallas and (on_tpu or interpret):
-            block_kg = min(max(128, block_k // g), max(128, kg))
-            pp = _pad_to(_pad_to(payload, block_n, 0), block_kg, -1)
-            # planar order is group-major, so the grouped view is a
-            # reshape — but the byte-axis block pad must land INSIDE each
-            # group
-            xg = _pad_to(_pad_to(xp, block_m, 0).reshape(-1, g, kg),
-                         block_kg, -1)
-            sg = _pad_to(sp.reshape(g, kg), block_kg, -1)
-            tp = _pad_to(row_scale, block_n, 0)
+            bm, bn, bkg = packed_blocks(m, kg, n, nbits)
+            bm, bn = block_m or bm, block_n or bn
+            if block_k:
+                bkg = min(max(128, block_k // g), max(128, kg))
+            # x·s once per call, over the real rows, as three exact bf16
+            # terms; each row block stacks its terms as rows, per group
+            # (planar order is group-major, so the grouped view is a
+            # reshape — but a byte-axis block pad must land INSIDE each
+            # group)
+            xs = split_bf16(xp.astype(jnp.float32)
+                            * sp.astype(jnp.float32)[None, :])
+            xs = _pad_to(xs, bm, 1)
+            mb = xs.shape[1] // bm
+            xs = xs.reshape(SPLIT_TERMS, mb, bm, g, kg).transpose(
+                1, 3, 0, 2, 4).reshape(mb, g, SPLIT_TERMS * bm, kg)
+            xs = _pad_to(xs, bkg, -1)
+            pp = _pad_to(_pad_to(payload, bn, 0), bkg, -1)
+            tp = _pad_to(row_scale, bn, 0)
             out = dequant_matmul_packed_pallas(
-                xg, pp, sg, tp, nbits=nbits, block_m=block_m,
-                block_n=block_n, block_kg=block_kg,
-                interpret=interpret or not on_tpu)[:m, :n]
+                xs, pp, tp, nbits=nbits, block_m=bm, block_n=bn,
+                block_kg=bkg, interpret=interpret or not on_tpu)[:m, :n]
         else:
             out = dequant_matmul_packed_ref(xp, payload, sp, row_scale,
                                             nbits=nbits)

@@ -308,3 +308,83 @@ def test_payload_nbits_discriminates_formats():
     for nbits in (2, 3, 4):
         payload, *_ = pack_codes_jnp(jnp.asarray(z), nbits=nbits)
         assert payload_nbits(payload) == nbits
+
+
+# ---------------------------------------------------------------------------
+# Exact bf16 contraction and shape-chosen blocks (DESIGN.md §8)
+# ---------------------------------------------------------------------------
+
+
+def test_split_bf16_terms_sum_exactly():
+    """hi + mid + lo rebuilds every f32 exactly, across magnitudes."""
+    from repro.kernels.dequant.dequant_matmul import split_bf16
+    rng = np.random.default_rng(23)
+    xs = (rng.standard_normal(4096)
+          * 10.0 ** rng.integers(-20, 20, 4096)).astype(np.float32)
+    terms = split_bf16(jnp.asarray(xs))
+    assert terms.shape == (3, 4096) and terms.dtype == jnp.bfloat16
+    t = np.asarray(terms.astype(jnp.float32), np.float64)
+    np.testing.assert_array_equal(t.sum(0), xs.astype(np.float64))
+
+
+@pytest.mark.parametrize("m", [1, 8, 130])
+@pytest.mark.parametrize("nbits", [2, 3, 4])
+def test_packed_kernel_matches_f32_highest(nbits, m):
+    """The kernel's three-term bf16 contraction keeps f32 accuracy: it
+    matches an f32 ``highest`` reference to rtol 1e-6 (interpret mode),
+    over ragged in-features, at decode, prefill and multi-block rows."""
+    import jax
+    k, n = 301, 200
+    lo, hi = -(2 ** (nbits - 1)), 2 ** (nbits - 1)
+    rng = np.random.default_rng(100 * nbits + m)
+    z = rng.integers(lo, hi, (n, k)).astype(np.int32)
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    s = jnp.asarray(rng.random(k) * 0.2 + 0.01, jnp.float32)
+    t = jnp.asarray(rng.random(n) + 0.5, jnp.float32)
+    payload, er, _, _ = pack_codes_jnp(jnp.asarray(z), nbits=nbits)
+    assert er.shape[0] == 0
+    out = np.asarray(dequant_matmul(x, payload, s, t, interpret=True))
+    with jax.default_matmul_precision("highest"):
+        ref = np.asarray(dequant_matmul_ref(x, jnp.asarray(z), s, t))
+    np.testing.assert_allclose(out, ref, rtol=1e-6,
+                               atol=1e-6 * np.abs(ref).max())
+
+
+#: (in, out) widths of minicpm-2b (attention, w_gate/w_up, w_out) and of
+#: minitron-8b (w_up, w_out)
+SERVED_WIDTHS = [(2304, 2304), (2304, 5760), (5760, 2304), (4096, 16384),
+                 (16384, 4096)]
+#: grid steps one matrix may take at decode rows
+MAX_GRID_STEPS = {4: 32, 3: 64, 2: 64}
+
+
+@pytest.mark.parametrize("m", [1, 5, 8])
+@pytest.mark.parametrize("nbits", [2, 3, 4])
+@pytest.mark.parametrize("k,n", SERVED_WIDTHS)
+def test_packed_blocks_fit_served_shapes(k, n, nbits, m):
+    """At the served widths the blocks divide the payload (no per-call
+    pad of its byte or row axis), pad the rows only to a multiple of 8,
+    and give each matrix a few tens of grid steps."""
+    from repro.kernels.dequant.dequant_matmul import (
+        PLANE_GROUPS, VMEM_BUDGET, _vmem_bytes, packed_blocks)
+    kg = k // PLANE_GROUPS[nbits]
+    bm, bn, bkg = packed_blocks(m, kg, n, nbits)
+    assert bm == 8
+    assert kg % bkg == 0 and (bkg % 128 == 0 or bkg == kg)
+    assert n % bn == 0 and (bn % 128 == 0 or bn == n)
+    assert (n // bn) * (kg // bkg) <= MAX_GRID_STEPS[nbits]
+    assert _vmem_bytes(bm, bn, bkg, nbits) <= VMEM_BUDGET
+
+
+def test_packed_blocks_rows_and_fallback():
+    """Rows: m rounded up to 8, 128 above that.  A byte axis with no
+    128-multiple divisor that fits goes whole if it fits, else is padded
+    to a 128-multiple and blocked."""
+    from repro.kernels.dequant.dequant_matmul import packed_blocks
+    assert [packed_blocks(m, 1152, 2304, 4)[0]
+            for m in (1, 7, 8, 9, 64, 128, 129, 512)] == [
+                8, 8, 8, 16, 64, 128, 128, 128]
+    assert packed_blocks(8, 2880, 2304, 4)[2] == 2880     # whole axis
+    assert packed_blocks(8, 150, 200, 4)[1:] == (200, 150)
+    bm, bn, bkg = packed_blocks(128, 40000 + 8, 2304, 4)  # 8 · 5001
+    assert bkg % 128 == 0 and bkg < 40008

@@ -7,9 +7,14 @@ compiler describes without hardware) and asserts the Mosaic kernel
 accepts the kernel's tiling and VMEM use at real widths, and no XLA
 reference twin took its place.  The ops pick the Pallas branch from
 ``jax.default_backend()``, which still reports the CPU here, so each test
-steers that one call to ``"tpu"`` for the duration of its compile.
+steers that one call to ``"tpu"`` for the duration of its compile.  The
+packed kernel's Mosaic module is read as it is serialized, to check the
+operand types of its MXU contractions.
 """
+import re
+
 import jax
+import jax._src.tpu_custom_call as tpu_custom_call
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
@@ -18,7 +23,11 @@ from repro.kernels.dequant import ops
 
 #: minicpm-2b MLP widths: d_model → d_ff (w_gate/w_up) and d_ff → d_model
 MLP_SHAPES = [(2304, 5760), (5760, 2304)]
+#: the same and the attention projections (d_model → d_model)
+PACKED_SHAPES = MLP_SHAPES + [(2304, 2304)]
 DECODE_ROWS = 4
+#: rows the engine sends the packed kernel: one prompt token, a decode step
+PACKED_ROWS = [1, 8]
 #: planar payload shape (n, k) → uint8 payload, by nbits (core/packing)
 PAYLOAD = {4: lambda n, k: (n, -(-k // 2)),
            3: lambda n, k: (n, 3, -(-k // 8)),
@@ -56,19 +65,45 @@ def _compiled_text(monkeypatch, jitted, args, **static):
     return jitted.lower(*args, **static).compile().as_text()
 
 
-@pytest.mark.parametrize("k,n", MLP_SHAPES)
+def _mosaic_modules(monkeypatch):
+    """Record the text of every Mosaic module serialized from now on."""
+    texts = []
+    serialize = tpu_custom_call._lower_mosaic_module_to_asm
+
+    def record(module, **kw):
+        texts.append(str(module))
+        return serialize(module, **kw)
+    monkeypatch.setattr(tpu_custom_call, "_lower_mosaic_module_to_asm",
+                        record)
+    return texts
+
+
+@pytest.mark.parametrize("m", PACKED_ROWS)
+@pytest.mark.parametrize("k,n", PACKED_SHAPES)
 @pytest.mark.parametrize("nbits", [4, 3, 2])
-def test_packed_kernel_compiles_for_v5e(one_chip, monkeypatch, nbits, k, n):
+def test_packed_kernel_compiles_for_v5e(one_chip, monkeypatch, nbits, k, n,
+                                        m):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    args = (sds((DECODE_ROWS, k), jnp.bfloat16),
+    args = (sds((m, k), jnp.bfloat16),
             sds(PAYLOAD[nbits](n, k), jnp.uint8),
             sds((k,), jnp.float32), sds((n,), jnp.float32))
+    jax.clear_caches()          # lower afresh, so the module is serialized
+    modules = _mosaic_modules(monkeypatch)
     text = _compiled_text(monkeypatch, ops._dequant_matmul_packed, args,
                           nbits=nbits)
     assert "tpu_custom_call" in text
     # the kernel's name is its instruction's, which a device trace shows
     assert f"%dequant_matmul_packed_int{nbits}" in text
+    # the MXU contracts exact bf16 codes against bf16 terms of x·s, never
+    # f32 × f32 (emulated in several bf16 passes)
+    matmuls = [line for mod in modules for line in mod.splitlines()
+               if "tpu.matmul" in line]
+    assert matmuls
+    for line in matmuls:
+        lhs, rhs = re.search(r":\s*vector<([^>]+)>,\s*vector<([^>]+)>",
+                             line).groups()
+        assert lhs.endswith("bf16") and rhs.endswith("bf16"), line
 
 
 @pytest.mark.parametrize("k,n", MLP_SHAPES)
