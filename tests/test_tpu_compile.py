@@ -1,7 +1,8 @@
-"""Compile the serving kernels for a described TPU v5e at minicpm-2b widths.
+"""Compile the serving kernels and programs for a described TPU v5e at the
+widths of the served configurations (minicpm-2b, minitron-8b).
 
-Nothing runs here.  Each test lowers a public dequant-matmul op's jitted
-body for one chip of a ``v5e:2x2`` topology (which the installed TPU
+Nothing runs here.  Each kernel test lowers a public dequant-matmul op's
+jitted body for one chip of a ``v5e:2x2`` topology (which the installed TPU
 compiler describes without hardware) and asserts the Mosaic kernel
 (``tpu_custom_call``) is in the compiled program: the chip's compiler
 accepts the kernel's tiling and VMEM use at real widths, and no XLA
@@ -9,9 +10,13 @@ reference twin took its place.  The ops pick the Pallas branch from
 ``jax.default_backend()``, which still reports the CPU here, so each test
 steers that one call to ``"tpu"`` for the duration of its compile.  The
 packed kernel's Mosaic module is read as it is serialized, to check the
-operand types of its MXU contractions.
+operand types of its MXU contractions.  The serving test compiles the
+engine's decode step and admission chunk at minitron-8b's full size and
+reads their memory from the compiler.
 """
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax._src.tpu_custom_call as tpu_custom_call
@@ -26,8 +31,20 @@ MLP_SHAPES = [(2304, 5760), (5760, 2304)]
 #: the same and the attention projections (d_model → d_model)
 PACKED_SHAPES = MLP_SHAPES + [(2304, 2304)]
 DECODE_ROWS = 4
+#: minitron-8b widths: wq, wk and wv, wo, w_in, w_out
+MINITRON_SHAPES = [(4096, 6144), (4096, 1024), (6144, 4096), (4096, 16384),
+                   (16384, 4096)]
 #: rows the engine sends the packed kernel: one prompt token, a decode step
+#: of minicpm-2b's 8 slots
 PACKED_ROWS = [1, 8]
+#: (nbits, k, n, m): every rung at minicpm-2b's widths and rows, and int4
+#: (the rung the benchmark serves) at the 16 rows of minitron-8b's decode
+#: step, at minicpm-2b's widths and at minitron-8b's
+PACKED_CASES = (
+    [(nbits, k, n, m) for nbits in (4, 3, 2) for k, n in PACKED_SHAPES
+     for m in PACKED_ROWS]
+    + [(4, k, n, 16) for k, n in PACKED_SHAPES]
+    + [(4, k, n, m) for k, n in MINITRON_SHAPES for m in PACKED_ROWS + [16]])
 #: planar payload shape (n, k) → uint8 payload, by nbits (core/packing)
 PAYLOAD = {4: lambda n, k: (n, -(-k // 2)),
            3: lambda n, k: (n, 3, -(-k // 8)),
@@ -78,9 +95,7 @@ def _mosaic_modules(monkeypatch):
     return texts
 
 
-@pytest.mark.parametrize("m", PACKED_ROWS)
-@pytest.mark.parametrize("k,n", PACKED_SHAPES)
-@pytest.mark.parametrize("nbits", [4, 3, 2])
+@pytest.mark.parametrize("nbits,k,n,m", PACKED_CASES)
 def test_packed_kernel_compiles_for_v5e(one_chip, monkeypatch, nbits, k, n,
                                         m):
     def sds(shape, dtype):
@@ -115,3 +130,51 @@ def test_int8_kernel_compiles_for_v5e(one_chip, monkeypatch, k, n):
     text = _compiled_text(monkeypatch, ops._dequant_matmul_int8, args)
     assert "tpu_custom_call" in text
     assert "%dequant_matmul_int8" in text
+
+
+def test_minitron_serving_programs_fit_v5e(one_chip, monkeypatch):
+    """minitron-8b.int4 as the benchmark serves it (bench/configs), on one
+    v5e: the decode step over 16 slots x 640 positions, and an admission
+    burst of 16 rows (``decode_chunk`` at (16, 16)) beside the engine's own
+    cache, fit 16 GiB by the compiler's own count."""
+    bench = str(Path(__file__).resolve().parents[1] / "bench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import harness
+    import weights
+    from spec import load_spec
+
+    from repro.models.transformer import init_cache
+    from repro.serve.engine import _serving_programs
+    spec = load_spec("minitron-8b.int4")
+    cfg = harness.program_config(spec)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(
+        lambda k: weights._program_tree(spec, k), weights.seed_key(0)))
+    slots = spec.slots
+
+    def cache(batch, per_slot):
+        return on_chip(jax.eval_shape(lambda: init_cache(
+            cfg, batch, spec.max_len, jnp.bfloat16, per_slot=per_slot)))
+    engine_cache = cache(slots, True)
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(engine_cache))
+    step, chunk = _serving_programs(cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
+    burst = jax.ShapeDtypeStruct((slots, spec.prefill_chunk), jnp.int32,
+                                 sharding=one_chip)
+    budget = 16 * 2**30
+    for compiled, resident in (
+            (step.lower(params, engine_cache, toks).compile(), 0),
+            (chunk.lower(params, cache(slots, False), burst).compile(),
+             cache_bytes)):
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+                 + resident)
+        assert total < budget, (total / 2**30, mem)
