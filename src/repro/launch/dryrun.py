@@ -124,12 +124,12 @@ def _cache_specs(cfg: ArchConfig, cache_sds, mesh):
         if nd == 0:
             return NamedSharding(mesh, P())
         dp = _dp_if_divisible(x.shape[1] if nd >= 2 else 1, mesh)
-        if nd == 5:  # kv (L,B,buf,n_kv,hd) | rwkv wkv (L,B,H,dk,dv)
-            head_axis = mdl_if(x.shape[3])
-            if kv_seq and head_axis is None and mdl_if(x.shape[2]):
+        if nd == 5:  # kv (L,B,n_kv/P,buf,P*hd) | rwkv wkv (L,B,H,dk,dv)
+            head_axis = mdl_if(x.shape[2])
+            if kv_seq and head_axis is None and mdl_if(x.shape[3]):
                 # §Perf kv_seq_shard: fall back to sharding the seq dim
-                return NamedSharding(mesh, P(None, dp, "model", None, None))
-            return NamedSharding(mesh, P(None, dp, None, head_axis, None))
+                return NamedSharding(mesh, P(None, dp, None, "model", None))
+            return NamedSharding(mesh, P(None, dp, head_axis, None, None))
         if nd == 4:  # rglru conv state (L,B,cw,lru)
             return NamedSharding(mesh, P(None, dp, None, mdl_if(x.shape[3])))
         if nd == 3:  # shift states (L,B,d) / rec h (L,B,lru)

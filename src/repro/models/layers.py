@@ -229,18 +229,31 @@ def sinusoidal_positions(length, dim, dtype=jnp.float32):
 
 
 class KVCache(NamedTuple):
-    """Ring-buffered KV cache: buffer length = window (local attn) or
-    max_len (global attn).
+    """Layer-stacked, ring-buffered KV cache: buffer length = window (local
+    attn) or max_len (global attn); axis 0 is the layer.
+
+    A row of the buffer holds P = :func:`kv_heads_per_row` heads side by
+    side, (n_kv / P, buf, P·hd), so that its minor axis fills the 128
+    lanes of a TPU vector (P = 2 at hd 64): a token's K/V is one sublane
+    of n_kv·hd / 128 tiles, and the scores and the value sum read each
+    row of heads as it lies.  Positions minor instead would spread a
+    token over n_kv·hd / 16 tiles.
 
     §Perf int8_kv: k/v stored int8 with EXACT per-(position, head) scales
-    (k_scale/v_scale, shape (B, buf, n_kv, 1)) — the same
+    (k_scale/v_scale, shape (L, B, n_kv / P, buf, P)) — the same
     per-dimension-scale idea as WaterSIC's per-column α, applied to the
     cache; halves the dominant decode HBM term vs bf16."""
 
-    k: jnp.ndarray  # (B, buf, n_kv, hd)
-    v: jnp.ndarray  # (B, buf, n_kv, hd)
-    k_scale: Any = ()   # (B, buf, n_kv, 1) f32 when int8, else ()
+    k: jnp.ndarray  # (L, B, n_kv / P, buf, P * hd)
+    v: jnp.ndarray  # (L, B, n_kv / P, buf, P * hd)
+    k_scale: Any = ()   # (L, B, n_kv / P, buf, P) f32 when int8, else ()
     v_scale: Any = ()
+
+
+def kv_heads_per_row(n_kv: int, head_dim: int) -> int:
+    """KV heads a cache row holds side by side: as many as fill 128 lanes
+    and divide ``n_kv``; 1 at hd ≥ 128."""
+    return math.gcd(n_kv, max(1, 128 // head_dim))
 
 
 def attention_init(key, d_model, n_q, n_kv, head_dim, *, bias=False,
@@ -395,18 +408,57 @@ def attention_train(p, x, *, n_q, n_kv, head_dim, rope_theta=10000.0,
     return out
 
 
-def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
-                     rope_theta=10000.0, window: Optional[int] = None,
-                     use_rope=True):
-    """Single-token decode against a (ring-buffered) cache.
+#: positions a cache write reads back and rewrites around its token: an
+#: update one position wide lets TPU layout assignment move the buffer
+#: axis, a whole-cache relayout on entry and exit
+_PUT_WINDOW = 8
 
-    x_t: (B, 1, d); pos: absolute position of this token — either a scalar
-    int32 (lockstep: every batch row sits at the same offset) or a (B,)
-    int32 vector (continuous batching, DESIGN.md §9: each *slot* carries its
-    own position, so slots at different sequence offsets decode in one
-    dispatch).  For local attention the buffer length equals the window and
-    indexing is mod-window; entries older than ``window`` are masked out by
-    recency.
+
+def _put_token(big, new, layer, slot):
+    """``big`` (L, B, rows of heads, buf, ·) with each row's token of
+    ``new`` (B, 1, n_kv, ·) written at (layer, row, slot): ``slot`` is a
+    scalar for every row or a (B,) vector, one per row.  A row whose slot
+    lies outside [0, buf) writes nothing.
+
+    Each write is a ``dynamic_update_slice`` of a window of positions read
+    from ``big`` with the token selected in (one for all rows at a scalar
+    slot, one per row otherwise), so it updates the stack in place."""
+    b, buf = big.shape[1], big.shape[3]
+    w = min(_PUT_WINDOW, buf)
+    new = new.reshape(b, big.shape[2], 1, big.shape[4]).astype(big.dtype)
+    blocks = ([(0, b, slot)] if jnp.ndim(slot) == 0
+              else [(r, 1, slot[r]) for r in range(b)])
+    for row, rows, s in blocks:
+        start = jnp.clip(s, 0, buf - w)
+        at = (layer, row, 0, start, 0)
+        cur = jax.lax.dynamic_slice(big, at, (1, rows, big.shape[2], w,
+                                              big.shape[4]))
+        hit = (start + jnp.arange(w) == s)[:, None]
+        val = jnp.where(hit, new[row:row + rows][None], cur)
+        big = jax.lax.dynamic_update_slice(big, val, at)
+    return big
+
+
+def attention_decode(p, x_t, cache: KVCache, layer, pos, *, n_q, n_kv,
+                     head_dim, rope_theta=10000.0,
+                     window: Optional[int] = None, use_rope=True):
+    """Single-token decode of layer ``layer`` against the layer-stacked
+    (ring-buffered) cache.
+
+    x_t: (B, 1, d); ``cache`` leaves are (L, B, n_kv / P, buf, P·hd) (see
+    :class:`KVCache`), every layer's buffer in one array, and ``layer`` is
+    a (traced) int32 index into it.  The new token's K/V is written at
+    (layer, row, slot) of the stack, so a layer scan that carries the
+    cache updates it in place; the scores and the value sum read layer
+    ``layer`` by a dynamic index that XLA fuses into them, so no layer's
+    buffer is sliced out or written back.  Returns
+    (out, the updated stack).  pos: absolute position of this token —
+    either a scalar int32 (lockstep: every batch row sits at the same
+    offset) or a (B,) int32 vector (continuous batching, DESIGN.md §9: each
+    *slot* carries its own position, so slots at different sequence offsets
+    decode in one dispatch).  For local attention the buffer length equals
+    the window and indexing is mod-window; entries older than ``window``
+    are masked out by recency.
     """
     b = x_t.shape[0]
     from repro.dist.sharding import manual_axis_info
@@ -414,10 +466,10 @@ def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
     # Sharded serving (DESIGN.md §13): inside the shard_map body each
     # device holds a contiguous 1/S block of the KV ring buffer (buffer
     # axis over "model").  Slot arithmetic and masking stay GLOBAL; only
-    # the scatter targets the local block, and K/V are re-assembled by an
-    # activation-sized all_gather before the scores.
+    # the write targets the local block, and the layer's K/V are
+    # re-assembled by an activation-sized all_gather before the scores.
     kv_sharded = bool(_ctx and _ctx.get("cache_sharded"))
-    buf_loc = cache.k.shape[1]
+    buf_loc = cache.k.shape[3]
     buf = buf_loc * _ctx["shards"] if kv_sharded else buf_loc
     pos = jnp.asarray(pos)
     per_slot = pos.ndim == 1
@@ -431,31 +483,17 @@ def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
             k_t = rope(k_t, posv, rope_theta)
     slot = pos % buf if window is not None else pos
     if kv_sharded:
-        # every row scatters into the LOCAL block: global slot minus this
-        # device's base offset.  Negative python-style wrapping would alias
-        # live data, so non-owned rows are first mapped to the (OOB) local
-        # buffer length and then dropped by the scatter.
-        rows = jnp.arange(b)
-        slot_vec = slot if per_slot else jnp.full((b,), slot)
-        base = jax.lax.axis_index(_ctx["axis"]) * buf_loc
-        loc = slot_vec - base
-        loc = jnp.where((loc >= 0) & (loc < buf_loc), loc, buf_loc)
-
-        def upd(big, new):
-            return big.at[rows, loc].set(new[:, 0].astype(big.dtype),
-                                         mode="drop")
+        # every row writes into the LOCAL block: global slot minus this
+        # device's base offset; a row whose slot another device holds
+        # falls outside the block and writes nothing
+        put_at = slot - jax.lax.axis_index(_ctx["axis"]) * buf_loc
     elif per_slot:
-        # one scatter row per batch element, each at its own slot; a row
-        # whose slot is out of range (an idle serving slot stepped past the
-        # buffer) is dropped by the scatter, never clamped onto live data
-        rows = jnp.arange(b)
-
-        def upd(big, new):
-            return big.at[rows, slot].set(new[:, 0].astype(big.dtype))
+        # a row whose slot is out of range (an idle serving slot stepped
+        # past the buffer) writes nothing, never clamped onto live data
+        put_at = slot
     else:
-        def upd(big, new):
-            return jax.lax.dynamic_update_slice_in_dim(
-                big, new.astype(big.dtype), slot, axis=1)
+        # lockstep keeps dynamic_update_slice's clamp at the buffer's end
+        put_at = jnp.minimum(slot, buf - 1)
     int8_kv = cache.k.dtype == jnp.int8
     k_scale, v_scale = cache.k_scale, cache.v_scale
     from repro.dist.sharding import current_mesh
@@ -469,46 +507,55 @@ def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
                         s_t.astype(jnp.float32))
             k_t_c, ks_t = q8(k_t)
             v_t_c, vs_t = q8(v_t)
-            k = upd(cache.k, k_t_c)
-            v = upd(cache.v, v_t_c)
-            k_scale = upd(cache.k_scale, ks_t)
-            v_scale = upd(cache.v_scale, vs_t)
+            k = _put_token(cache.k, k_t_c, layer, put_at)
+            v = _put_token(cache.v, v_t_c, layer, put_at)
+            k_scale = _put_token(cache.k_scale, ks_t, layer, put_at)
+            v_scale = _put_token(cache.v_scale, vs_t, layer, put_at)
         else:
-            k = upd(cache.k, k_t)
-            v = upd(cache.v, v_t)
+            k = _put_token(cache.k, k_t, layer, put_at)
+            v = _put_token(cache.v, v_t, layer, put_at)
         mesh = current_mesh()
         msize = dict(getattr(mesh, "shape", {})).get("model", 1) \
             if mesh else 1
         if _opt("kv_seq_shard") and n_kv % msize \
-                and k.shape[1] % msize == 0:
+                and k.shape[3] % msize == 0:
             # §Perf kv_seq_shard: shard the cache SEQ dim over "model" —
             # avoids replicating the cache when kv-head count doesn't
             # divide the axis (GQA kv=8 / MHA 36-40 heads on a 16-way axis)
-            k = logical_shard(k, "batch", "kv_seq", None, None)
-            v = logical_shard(v, "batch", "kv_seq", None, None)
+            k = logical_shard(k, "layers", "batch", None, "kv_seq", None)
+            v = logical_shard(v, "layers", "batch", None, "kv_seq", None)
         else:
-            k = logical_shard(k, "batch", None, "kv_heads", None)
-            v = logical_shard(v, "batch", None, "kv_heads", None)
+            k = logical_shard(k, "layers", "batch", "kv_heads", None, None)
+            v = logical_shard(v, "layers", "batch", "kv_heads", None, None)
     with jax.named_scope("attention"):
-        if kv_sharded:
-            # reassemble the global ring buffer for the scores — an
-            # activation-sized gather (this step's K/V), never weights;
-            # shard s holds global slots [s*buf_loc, (s+1)*buf_loc), so
-            # the tiled gather reproduces the oracle's buffer ordering
-            def _gather(a):
-                return jax.lax.all_gather(a, _ctx["axis"], axis=1,
-                                          tiled=True)
-            k_full, v_full = _gather(k), _gather(v)
-            ks_full = _gather(k_scale) if int8_kv else k_scale
-            vs_full = _gather(v_scale) if int8_kv else v_scale
-        else:
-            k_full, v_full, ks_full, vs_full = k, v, k_scale, v_scale
-        k_eff = (k_full.astype(q.dtype) * ks_full.astype(q.dtype)) \
-            if int8_kv else k_full
-        v_eff = (v_full.astype(q.dtype) * vs_full.astype(q.dtype)) \
-            if int8_kv else v_full
+        def at_layer(a):
+            a = jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            if kv_sharded:
+                # reassemble the layer's global ring buffer for the scores
+                # — an activation-sized gather (this step's K/V), never
+                # weights; shard s holds global slots [s*buf_loc,
+                # (s+1)*buf_loc), so the tiled gather reproduces the
+                # oracle's buffer ordering
+                a = jax.lax.all_gather(a, _ctx["axis"], axis=2, tiled=True)
+            return a
+        k_l, v_l = at_layer(k), at_layer(v)          # (B, R, buf, P·hd)
+        if int8_kv:
+            def scaled(a, s):
+                return a.astype(q.dtype) * jnp.repeat(
+                    at_layer(s).astype(q.dtype), head_dim, axis=-1)
+            k_l, v_l = scaled(k_l, k_scale), scaled(v_l, v_scale)
+        # a row of the cache holds P heads side by side: each query meets
+        # its own head's lanes of the row, zeros on the others' (P = 1 at
+        # hd ≥ 128), so the scores read every row of heads as it lies
+        n_rows, g = k_l.shape[1], n_q // n_kv
+        heads = n_kv // n_rows
+        eye = jnp.eye(heads, dtype=q.dtype)
+        qg = q.reshape(b, n_rows, heads, g, 1, head_dim)
+        qz = (qg * eye[:, None, :, None]).reshape(
+            b, n_rows, heads, g, heads * head_dim)
         # (B, nkv, G, 1, buf)
-        scores = _attn_scores(q, k_eff, 1.0 / math.sqrt(head_dim))
+        scores = jnp.einsum("brpgx,brtx->brpgt", qz, k_l).reshape(
+            b, n_kv, g, 1, buf) * (1.0 / math.sqrt(head_dim))
         idx = jnp.arange(buf)
         if per_slot:
             # (B, buf) mask: every slot masks by ITS OWN position
@@ -531,7 +578,13 @@ def attention_decode(p, x_t, cache: KVCache, pos, *, n_q, n_kv, head_dim,
             scores = jnp.where(valid[None, None, None, None, :], scores,
                                -1e30)
         probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = _attn_out(probs.astype(x_t.dtype), v_eff)
+        out = jnp.einsum("brpgt,brtx->brpgx",
+                         probs.astype(x_t.dtype).reshape(
+                             b, n_rows, heads, g, buf), v_l)
+        # each head keeps its own lanes of the row
+        out = jnp.einsum("brpgqh,pq->brpgh", out.reshape(
+            b, n_rows, heads, g, heads, head_dim), eye)
+        out = out.reshape(b, 1, n_q * head_dim)
     with jax.named_scope("attn_proj"):
         out = dense(p["wo"], out)
     return out, KVCache(k=k, v=v, k_scale=k_scale, v_scale=v_scale)

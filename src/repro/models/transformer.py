@@ -34,9 +34,10 @@ from . import rglru as rg
 from . import rwkv6 as rk
 from .layers import (KVCache, KeyGen, Px, attention_decode, attention_init,
                      attention_train, cross_attention_decode, dense,
-                     dense_init, embed, embed_init, layernorm, layernorm_init,
-                     mlp, mlp_init, moe, moe_init, rmsnorm, rmsnorm_init,
-                     scoped, sinusoidal_positions, split_tree, unembed)
+                     dense_init, embed, embed_init, kv_heads_per_row,
+                     layernorm, layernorm_init, mlp, mlp_init, moe, moe_init,
+                     rmsnorm, rmsnorm_init, scoped, sinusoidal_positions,
+                     split_tree, unembed)
 
 __all__ = ["init_params", "forward_train", "loss_fn", "prefill", "init_cache",
            "decode_step", "param_specs_tree", "cache_write_slot",
@@ -372,10 +373,12 @@ class DecodeCache(NamedTuple):
 
 def _kv_buf(cfg, batch, buf_len, dtype, n_layers=None):
     nl = n_layers if n_layers is not None else cfg.n_layers
-    shape = (nl, batch, buf_len, cfg.n_kv, cfg.resolved_head_dim)
+    hd = cfg.resolved_head_dim
+    per_row = kv_heads_per_row(cfg.n_kv, hd)
+    shape = (nl, batch, cfg.n_kv // per_row, buf_len, per_row * hd)
     from repro.opts import enabled as _opt
     if _opt("int8_kv"):
-        sshape = (nl, batch, buf_len, cfg.n_kv, 1)
+        sshape = shape[:-1] + (per_row,)
         return KVCache(k=jnp.zeros(shape, jnp.int8),
                        v=jnp.zeros(shape, jnp.int8),
                        k_scale=jnp.zeros(sshape, jnp.float32),
@@ -542,13 +545,16 @@ def _prefill_hybrid_parallel(cfg, params, batch, max_len, cache_dtype):
     ak = _attn_kwargs(cfg)
     buf = min(max_len, cfg.local_window or max_len)
 
-    def ring_fill(k):  # (B, S, nkv, hd) -> (B, buf, nkv, hd) at slot p%buf
+    def ring_fill(k):  # (B, S, nkv, hd) -> (B, nkv/P, buf, P*hd), p%buf
         last = k[:, -buf:]
         pad = buf - last.shape[1]
         if pad > 0:
             last = jnp.pad(last, ((0, 0), (0, pad), (0, 0), (0, 0)))
         shift = s % buf if s >= buf else 0
-        return jnp.roll(last, shift, axis=1).astype(cache_dtype)
+        last = jnp.roll(last, shift, axis=1)
+        per_row = kv_heads_per_row(cfg.n_kv, cfg.resolved_head_dim)
+        last = last.reshape(b, buf, cfg.n_kv // per_row, -1)
+        return jnp.swapaxes(last, 1, 2).astype(cache_dtype)
 
     def group_block(carry, gp):
         y = carry
@@ -609,7 +615,7 @@ def _prefill_attn(cfg, params, batch, max_len, cache_dtype):
     # recompute per-layer K/V once more inside a capture scan would double
     # compute; instead capture via forward hooks: here we re-run the embed +
     # per-layer K/V projections only (cheap: 2·d·kv·hd per token).
-    kv = _capture_kv(cfg, params, batch, cache.kv.k.shape[2], cache_dtype)
+    kv = _capture_kv(cfg, params, batch, cache.kv.k.shape[3], cache_dtype)
     extras = None
     if cfg.family == "encdec":
         enc = _encode(cfg, params, batch["frames"])
@@ -640,11 +646,13 @@ def _capture_kv(cfg, params, batch, buf_len, cache_dtype):
     v_all = capture(attn_p["wv"]["w"]).astype(cache_dtype)
     L = k_all.shape[0]
     b, s = x.shape[0], x.shape[1]
-    k_all = k_all.reshape(L, b, s, nkv, hd)[:, :, -buf_len:]
-    v_all = v_all.reshape(L, b, s, nkv, hd)[:, :, -buf_len:]
+    rows = nkv // kv_heads_per_row(nkv, hd)
+    k_all = jnp.swapaxes(k_all.reshape(L, b, s, rows, -1), 2, 3)
+    v_all = jnp.swapaxes(v_all.reshape(L, b, s, rows, -1), 2, 3)
+    k_all, v_all = k_all[:, :, :, -buf_len:], v_all[:, :, :, -buf_len:]
     buf = _kv_buf(cfg, b, buf_len, cache_dtype, n_layers=L)
-    k_buf = jax.lax.dynamic_update_slice_in_dim(buf.k, k_all, 0, axis=2)
-    v_buf = jax.lax.dynamic_update_slice_in_dim(buf.v, v_all, 0, axis=2)
+    k_buf = jax.lax.dynamic_update_slice_in_dim(buf.k, k_all, 0, axis=3)
+    v_buf = jax.lax.dynamic_update_slice_in_dim(buf.v, v_all, 0, axis=3)
     return KVCache(k=k_buf, v=v_buf)
 
 
@@ -673,12 +681,15 @@ def decode_step(cfg: ArchConfig, params, cache: DecodeCache, token,
     if cfg.family in ("dense", "moe", "vlm"):
         window = cfg.local_window or None
 
+        # the stacked cache rides in the carry, so each layer writes its
+        # token into it in place by index: as scan xs/ys every layer's
+        # cache would be sliced out and written back whole each step
         def body(carry, lps):
-            h, = carry
-            lp, kv_l = lps
+            h, kv = carry
+            lp, layer = lps
             a_in = _norm(cfg, lp["ln_attn"], h)
-            a_out, kv_new = attention_decode(lp["attn"], a_in, kv_l, pos,
-                                             window=window, **ak)
+            a_out, kv = attention_decode(lp["attn"], a_in, kv, layer, pos,
+                                         window=window, **ak)
             h = h + a_out
             m_in = _norm(cfg, lp["ln_mlp"], h)
             if cfg.n_experts:
@@ -688,9 +699,11 @@ def decode_step(cfg: ArchConfig, params, cache: DecodeCache, token,
                             activation=cfg.activation)
             else:
                 m_out = mlp(lp["mlp"], m_in, activation=cfg.activation)
-            return (h + m_out,), kv_new
+            return (h + m_out, kv), None
 
-        (x,), kv = jax.lax.scan(body, (x,), (params["layers"], cache.kv))
+        (x, kv), _ = jax.lax.scan(body, (x, cache.kv),
+                                  (params["layers"],
+                                   jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         x = _norm(cfg, params["ln_f"], x)
         logits = unembed(params["embed"], x, cfg.vocab)[:, 0, :]
         return logits, DecodeCache(kv, pos + 1, cache.extras)
@@ -721,32 +734,27 @@ def decode_step(cfg: ArchConfig, params, cache: DecodeCache, token,
         n_groups = cfg.n_layers // len(pat)
         st = cache.kv
         kv, rec = st["kv"], st["rec"]
-        a_i = 0
-        r_i = 0
-        # scan over groups; attention/rec state indices advance per kind
+        # scan over groups; the attention stack rides in the carry (group
+        # g's a-th attention layer is entry g * n_attn_per_group + a), the
+        # recurrent states are scanned per group
         n_attn_per_group = sum(1 for t in pat if t == "attn")
         n_rec_per_group = len(pat) - n_attn_per_group
-        kv_g = jax.tree.map(
-            lambda t: t[:n_attn_per_group * n_groups].reshape(
-                (n_groups, n_attn_per_group) + t.shape[1:]), kv)
         rec_g = jax.tree.map(
             lambda t: t[:n_rec_per_group * n_groups].reshape(
                 (n_groups, n_rec_per_group) + t.shape[1:]), rec)
 
         def body(carry, lps):
-            h, = carry
-            gp, kv_l, rec_l = lps
+            h, kv = carry
+            gp, rec_l, g = lps
             ai, ri = 0, 0
-            kv_out, rec_out = [], []
+            rec_out = []
             for idx, kind in enumerate(pat):
                 sub = gp[f"b{idx}"]
                 t_in = _norm(cfg, sub["ln_t"], h)
                 if kind == "attn":
-                    kvi = jax.tree.map(lambda t: t[ai], kv_l)
-                    a_out, kv_new = attention_decode(
-                        sub["attn"], t_in, kvi, pos,
+                    a_out, kv = attention_decode(
+                        sub["attn"], t_in, kv, g * n_attn_per_group + ai, pos,
                         window=cfg.local_window or None, **ak)
-                    kv_out.append(kv_new)
                     h = h + a_out
                     ai += 1
                 else:
@@ -757,16 +765,13 @@ def decode_step(cfg: ArchConfig, params, cache: DecodeCache, token,
                     ri += 1
                 h = h + mlp(sub["mlp"], _norm(cfg, sub["ln_mlp"], h),
                             activation=cfg.activation)
-            kv_stack = jax.tree.map(lambda *ts: jnp.stack(ts), *kv_out) \
-                if kv_out else kv_l
             rec_stack = jax.tree.map(lambda *ts: jnp.stack(ts), *rec_out) \
                 if rec_out else rec_l
-            return (h,), (kv_stack, rec_stack)
+            return (h, kv), rec_stack
 
-        (x,), (kv_new_g, rec_new_g) = jax.lax.scan(
-            body, (x,), (params["groups"], kv_g, rec_g))
-        kv_new = jax.tree.map(
-            lambda t: t.reshape((-1,) + t.shape[2:]), kv_new_g)
+        (x, kv_new), rec_new_g = jax.lax.scan(
+            body, (x, kv),
+            (params["groups"], rec_g, jnp.arange(n_groups, dtype=jnp.int32)))
         rec_new = jax.tree.map(
             lambda t: t.reshape((-1,) + t.shape[2:]), rec_new_g)
         # unscanned tail: for the recurrentgemma pattern (rec, rec, attn)
@@ -807,11 +812,11 @@ def decode_step(cfg: ArchConfig, params, cache: DecodeCache, token,
         x = x + pos_emb.astype(x.dtype)
 
         def body(carry, lps):
-            h, = carry
-            lp, kv_l, ek, ev = lps
-            a_out, kv_new = attention_decode(
-                lp["self_attn"], _norm(cfg, lp["ln_self"], h), kv_l, pos,
-                use_rope=False, **ak)
+            h, kv = carry
+            lp, ek, ev, layer = lps
+            a_out, kv = attention_decode(
+                lp["self_attn"], _norm(cfg, lp["ln_self"], h), kv, layer,
+                pos, use_rope=False, **ak)
             h = h + a_out
             c_out = cross_attention_decode(
                 lp["cross_attn"], _norm(cfg, lp["ln_cross"], h), ek, ev,
@@ -820,10 +825,12 @@ def decode_step(cfg: ArchConfig, params, cache: DecodeCache, token,
             h = h + c_out
             h = h + mlp(lp["mlp"], _norm(cfg, lp["ln_mlp"], h),
                         activation="gelu")
-            return (h,), kv_new
+            return (h, kv), None
 
-        (x,), kv = jax.lax.scan(body, (x,),
-                                (params["dec_layers"], cache.kv, enc_k, enc_v))
+        (x, kv), _ = jax.lax.scan(
+            body, (x, cache.kv),
+            (params["dec_layers"], enc_k, enc_v,
+             jnp.arange(cfg.n_layers, dtype=jnp.int32)))
         x = _norm(cfg, params["ln_f"], x)
         logits = unembed(params["embed"], x, cfg.vocab)[:, 0, :]
         return logits, DecodeCache(kv, pos + 1, cache.extras)

@@ -212,14 +212,21 @@ def _run_prefill(decode_fn, decode_chunk_fn, params, cache,
 
 def _serving_programs(cfg: ArchConfig):
     """The jitted decode step and prefill chunk, named so that each device
-    op's module in a trace says which of the two it belongs to."""
+    op's module in a trace says which of the two it belongs to.
+
+    Both donate the cache they are handed (argument 1): the layer scan
+    writes each token into the stacked cache in place, and donation lets
+    that update alias the caller's buffer instead of a fresh copy.  The
+    input cache is deleted by the call, so a caller passes only a cache it
+    owns and replaces it with the returned one."""
     def serve_decode_step(params, cache, tok):
         return decode_step(cfg, params, cache, tok)
 
     def serve_prefill_chunk(params, cache, toks):
         return decode_chunk(cfg, params, cache, toks)
 
-    return jax.jit(serve_decode_step), jax.jit(serve_prefill_chunk)
+    return (jax.jit(serve_decode_step, donate_argnums=(1,)),
+            jax.jit(serve_prefill_chunk, donate_argnums=(1,)))
 
 
 class _EngineBase:
@@ -903,9 +910,12 @@ class ContinuousEngine(_EngineBase):
     def _decode_dispatch(self):
         """Chaos-hooked decode entry (device-loss / slow-step site).
 
-        Pure w.r.t. engine state: reads params/cache/_last, returns
-        (logits, new_cache) — the caller commits the cache only on
-        success, so a retried dispatch recomputes from identical inputs.
+        Reads params/cache/_last and returns (logits, new_cache); the
+        caller commits the cache only on success.  The step donates the
+        cache, so the call itself consumes ``self.cache``: a retry
+        recomputes from identical inputs only because every fault fires
+        here, BEFORE the jitted call.  Keep any new fault site ahead of
+        it.
         """
         if chaos.enabled():
             chaos.fire("serve.decode", engine=self)

@@ -257,16 +257,16 @@ def params_pspecs(params, *, axis_name: str = "model"):
 def cache_pspecs(cache, *, axis_name: str = "model", shards: int):
     """(spec tree, cache_sharded) for a decode cache.
 
-    KV buffers (the 5-D ``(L, B, buf, n_kv, hd)`` leaves, incl. int8-KV
+    KV buffers (the 5-D ``(L, B, n_kv / P, buf, P·hd)`` leaves, incl. int8-KV
     scale buffers) shard their buffer axis over ``axis_name`` when the
     buffer length divides evenly; otherwise the whole cache replicates
     (correct either way — attention gathers the sharded buffer back
     before scoring, see ``models.layers.attention_decode``).
     """
     leaves = [x for x in jax.tree.leaves(cache) if getattr(x, "ndim", 0) == 5]
-    sharded = bool(leaves) and all(x.shape[2] % shards == 0 for x in leaves)
+    sharded = bool(leaves) and all(x.shape[3] % shards == 0 for x in leaves)
     spec = jax.tree.map(
-        lambda x: P(None, None, axis_name)
+        lambda x: P(None, None, None, axis_name)
         if (sharded and getattr(x, "ndim", 0) == 5) else P(), cache)
     return spec, sharded
 

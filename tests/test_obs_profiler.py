@@ -152,7 +152,7 @@ def test_step_programs_carry_layer_scopes(program):
                   "packed_matmul"):
         assert any(f"/{scope}/" in n for n in op_names), scope
     # the cache write is the kv_cache scope's, the scores the attention's
-    assert any("/kv_cache/scatter" in n for n in op_names)
+    assert any("/kv_cache/dynamic_update_slice" in n for n in op_names)
     assert any(re.search(r"/attention/.*dot_general", n) for n in op_names)
 
 

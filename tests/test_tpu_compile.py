@@ -12,7 +12,8 @@ steers that one call to ``"tpu"`` for the duration of its compile.  The
 packed kernel's Mosaic module is read as it is serialized, to check the
 operand types of its MXU contractions.  The serving test compiles the
 engine's decode step and admission chunk at minitron-8b's full size and
-reads their memory from the compiler.
+reads their memory from the compiler; another holds both configurations'
+serving programs to updating the donated KV cache in place.
 """
 import re
 import sys
@@ -132,11 +133,10 @@ def test_int8_kernel_compiles_for_v5e(one_chip, monkeypatch, k, n):
     assert "%dequant_matmul_int8" in text
 
 
-def test_minitron_serving_programs_fit_v5e(one_chip, monkeypatch):
-    """minitron-8b.int4 as the benchmark serves it (bench/configs), on one
-    v5e: the decode step over 16 slots x 640 positions, and an admission
-    burst of 16 rows (``decode_chunk`` at (16, 16)) beside the engine's own
-    cache, fit 16 GiB by the compiler's own count."""
+def _serving_setup(one_chip, name):
+    """(spec, the engine's step and chunk, the parameters, a cache maker,
+    a token-array maker) for configuration ``name`` of the benchmark, every
+    argument placed on the described chip."""
     bench = str(Path(__file__).resolve().parents[1] / "bench")
     if bench not in sys.path:
         sys.path.append(bench)
@@ -146,7 +146,7 @@ def test_minitron_serving_programs_fit_v5e(one_chip, monkeypatch):
 
     from repro.models.transformer import init_cache
     from repro.serve.engine import _serving_programs
-    spec = load_spec("minitron-8b.int4")
+    spec = load_spec(name)
     cfg = harness.program_config(spec)
 
     def on_chip(tree):
@@ -154,23 +154,33 @@ def test_minitron_serving_programs_fit_v5e(one_chip, monkeypatch):
             a.shape, a.dtype, sharding=one_chip), tree)
     params = on_chip(jax.eval_shape(
         lambda k: weights._program_tree(spec, k), weights.seed_key(0)))
-    slots = spec.slots
 
     def cache(batch, per_slot):
         return on_chip(jax.eval_shape(lambda: init_cache(
             cfg, batch, spec.max_len, jnp.bfloat16, per_slot=per_slot)))
+    return (spec, _serving_programs(cfg), params, cache,
+            lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                               sharding=one_chip))
+
+
+def test_minitron_serving_programs_fit_v5e(one_chip, monkeypatch):
+    """minitron-8b.int4 as the benchmark serves it (bench/configs), on one
+    v5e: the decode step over 16 slots x 640 positions, and an admission
+    burst of 16 rows (``decode_chunk`` at (16, 16)) beside the engine's own
+    cache, fit 16 GiB by the compiler's own count."""
+    spec, (step, chunk), params, cache, toks = _serving_setup(
+        one_chip, "minitron-8b.int4")
+    slots = spec.slots
     engine_cache = cache(slots, True)
     cache_bytes = sum(a.size * a.dtype.itemsize
                       for a in jax.tree.leaves(engine_cache))
-    step, chunk = _serving_programs(cfg)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    toks = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
-    burst = jax.ShapeDtypeStruct((slots, spec.prefill_chunk), jnp.int32,
-                                 sharding=one_chip)
     budget = 16 * 2**30
     for compiled, resident in (
-            (step.lower(params, engine_cache, toks).compile(), 0),
-            (chunk.lower(params, cache(slots, False), burst).compile(),
+            (step.lower(params, engine_cache, toks((slots, 1))).compile(),
+             0),
+            (chunk.lower(params, cache(slots, False),
+                         toks((slots, spec.prefill_chunk))).compile(),
              cache_bytes)):
         assert "tpu_custom_call" in compiled.as_text()
         mem = compiled.memory_analysis()
@@ -178,3 +188,72 @@ def test_minitron_serving_programs_fit_v5e(one_chip, monkeypatch):
                  + mem.temp_size_in_bytes - mem.alias_size_in_bytes
                  + resident)
         assert total < budget, (total / 2**30, mem)
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = (\(.*?\)|\w+\[[\d,]*\]"
+                          r"(?:\{[^}]*\})?) ([\w\-]+)\(")
+_ARRAY = re.compile(r"\w+\[([\d,]*)\]")
+#: what may produce the whole stacked cache: an in-place update of it, or
+#: an op that only passes the buffer on
+_IN_PLACE = ("dynamic-update-slice", "scatter")
+_PASS_ON = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+
+
+def _top_level_instructions(text):
+    """(opcode, its fused root's opcode or None, output dims) of every
+    instruction in a computation that no fusion or reduction calls: the
+    entry and the loop bodies, the ops a device runs one by one."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif line.strip() == "}":
+            name = None
+        elif name is not None and (m := _INSTRUCTION.match(line)):
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            comps[name].append((m.group(1), m.group(4),
+                                called and called.group(1),
+                                [tuple(int(d) for d in dims.split(",") if d)
+                                 for dims in _ARRAY.findall(m.group(3))],
+                                " fusion(" in line or "to_apply=" in line))
+    inner = {called for body in comps.values()
+             for _, _, called, _, fusion in body if called and fusion}
+    inner |= set(re.findall(r"to_apply=%([\w.\-]+)", text))
+    roots = {n: op for n, body in comps.items()
+             for root, op, _, _, _ in body if root}
+    return [(op, roots.get(called), dims)
+            for n, body in comps.items() if n not in inner
+            for _, op, called, shapes, _ in body for dims in shapes]
+
+
+@pytest.mark.parametrize("name", ["minicpm-2b.int4", "minitron-8b.int4"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_serving_programs_update_cache_in_place(one_chip, monkeypatch, name,
+                                               program):
+    """The engine's decode step over its slots, and a one-row prefill
+    chunk, at each benchmark configuration's shapes on one v5e: the
+    compiled program aliases the donated cache to its output, no top-level
+    instruction materialises one layer's K or V (in any axis order), and
+    every instruction that produces the whole stacked cache updates it in
+    place."""
+    spec, (step, chunk), params, cache, toks = _serving_setup(one_chip,
+                                                             name)
+    rows = spec.slots if program == "decode_step" else 1
+    kv = cache(rows, program == "decode_step")
+    fn, tok = {"decode_step": (step, toks((rows, 1))),
+               "prefill_chunk": (chunk, toks((rows, spec.prefill_chunk)))}[
+                   program]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = fn.lower(params, kv, tok).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(kv))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    stack = kv.kv.k.shape                      # (L, B, n_kv, hd, buf)
+    layer = sorted(d for d in stack[1:] if d != 1)
+    for op, root, dims in _top_level_instructions(compiled.as_text()):
+        assert sorted(d for d in dims if d != 1) != layer, (op, root, dims)
+        if dims == stack:
+            assert op in _PASS_ON + _IN_PLACE or (
+                op == "fusion" and root in _IN_PLACE), (op, root, dims)
