@@ -12,8 +12,8 @@ Layout policy (single pod, ``(data, model)``):
 
   * ``batch`` / ``capacity``  → ``data``        (DP / MoE buffer rows)
   * ``d_model_w``             → ``data``        (FSDP: weight-stationary dim)
-  * ``heads`` ``kv_heads`` ``ff`` ``vocab`` ``experts`` ``state`` ``kv_seq``
-                              → ``model``       (TP / EP / cache-seq)
+  * ``heads`` ``kv_heads`` ``ff`` ``vocab`` ``experts`` ``state``
+                              → ``model``       (TP / EP)
   * ``seq`` ``frames`` ``d_model`` ``layers``   → replicated
 
 Multi-pod (``(pod, data, model)``) extends the DP/FSDP entries to
@@ -64,7 +64,6 @@ def default_rules(multi_pod: bool = False) -> Dict[str, Axis]:
         "frames": None,
         "d_model": None,
         "capacity": dp,
-        "kv_seq": "model",
         # weights (and the activation dims that mirror them)
         "d_model_w": dp,
         "heads": "model",
@@ -213,9 +212,9 @@ def logical_shard(x, *axes: Optional[str]):
     is bit-for-bit the untouched computation.  Under a mesh, per-dim
     entries are dropped when (a) the named mesh axes are absent from the
     active mesh or (b) their size product does not divide the dim (e.g. a
-    2-kv-head cache on a 4-way model axis — the kv_seq_shard fallback's
-    whole reason to exist).  Inside a :func:`manual_axes` scope (tracing a
-    shard_map body) it is likewise the strict identity.
+    2-kv-head cache on a 4-way model axis, which then replicates).
+    Inside a :func:`manual_axes` scope (tracing a shard_map body) it is
+    likewise the strict identity.
     """
     mesh = current_mesh()
     if mesh is None or in_manual_axes():

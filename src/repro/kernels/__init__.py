@@ -4,8 +4,8 @@
               bound matmul; the paper's systems payoff on TPU)
   zsic/     — blocked SIC quantizer (in-block recursion in VMEM, trailing
               update on the MXU) — TPU adaptation of GPTQ-style loops
-  flash/    — blockwise online-softmax attention (the §Perf dense-train
-              memory lever: no S×S score materialization)
+  flash/    — blockwise online-softmax attention (no S×S score
+              materialization); no model calls it yet (ROADMAP)
 
 Each kernel ships <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper with padding/dispatch) and ref.py (pure-jnp oracle); tests sweep

@@ -113,9 +113,6 @@ def _cache_specs(cfg: ArchConfig, cache_sds, mesh):
     """PartitionSpecs for decode caches: batch over DP (when divisible),
     kv-heads / state heads over model (when divisible)."""
 
-    from repro.opts import enabled as _opt
-    kv_seq = _opt("kv_seq_shard")
-
     def mdl_if(dim):
         return "model" if dim % mesh.shape["model"] == 0 else None
 
@@ -125,11 +122,8 @@ def _cache_specs(cfg: ArchConfig, cache_sds, mesh):
             return NamedSharding(mesh, P())
         dp = _dp_if_divisible(x.shape[1] if nd >= 2 else 1, mesh)
         if nd == 5:  # kv (L,B,n_kv/P,buf,P*hd) | rwkv wkv (L,B,H,dk,dv)
-            head_axis = mdl_if(x.shape[2])
-            if kv_seq and head_axis is None and mdl_if(x.shape[3]):
-                # §Perf kv_seq_shard: fall back to sharding the seq dim
-                return NamedSharding(mesh, P(None, dp, None, "model", None))
-            return NamedSharding(mesh, P(None, dp, head_axis, None, None))
+            return NamedSharding(mesh, P(None, dp, mdl_if(x.shape[2]), None,
+                                         None))
         if nd == 4:  # rglru conv state (L,B,cw,lru)
             return NamedSharding(mesh, P(None, dp, None, mdl_if(x.shape[3])))
         if nd == 3:  # shift states (L,B,d) / rec h (L,B,lru)

@@ -237,17 +237,10 @@ class KVCache(NamedTuple):
     lanes of a TPU vector (P = 2 at hd 64): a token's K/V is one sublane
     of n_kv·hd / 128 tiles, and the scores and the value sum read each
     row of heads as it lies.  Positions minor instead would spread a
-    token over n_kv·hd / 16 tiles.
-
-    §Perf int8_kv: k/v stored int8 with EXACT per-(position, head) scales
-    (k_scale/v_scale, shape (L, B, n_kv / P, buf, P)) — the same
-    per-dimension-scale idea as WaterSIC's per-column α, applied to the
-    cache; halves the dominant decode HBM term vs bf16."""
+    token over n_kv·hd / 16 tiles."""
 
     k: jnp.ndarray  # (L, B, n_kv / P, buf, P * hd)
     v: jnp.ndarray  # (L, B, n_kv / P, buf, P * hd)
-    k_scale: Any = ()   # (L, B, n_kv / P, buf, P) f32 when int8, else ()
-    v_scale: Any = ()
 
 
 def kv_heads_per_row(n_kv: int, head_dim: int) -> int:
@@ -296,62 +289,12 @@ def _attn_out(scores, v):
     return out.reshape(b, s, nkv * g * v.shape[-1])
 
 
-def _attention_blockwise(q, k, v, *, causal: bool, window: int,
-                         block_k: int = 512):
-    """Online-softmax blockwise attention in pure jnp (lax.scan over K
-    blocks) — never materializes the (S, S) score tensor.  XLA-level twin of
-    kernels/flash (the TPU-native Pallas version); lets the dry-run measure
-    the §Perf `blockwise_attention` memory win on the CPU backend.
-
-    q: (B, S, nq, hd); k/v: (B, T, nkv, hd).  T must divide block_k.
-    """
-    b, s, nq, hd = q.shape
-    t, nkv = k.shape[1], k.shape[2]
-    g = nq // nkv
-    scale = 1.0 / math.sqrt(hd)
-    qg = q.reshape(b, s, nkv, g, hd)
-    n_blocks = t // block_k
-    kb = jnp.moveaxis(k.reshape(b, n_blocks, block_k, nkv, hd), 1, 0)
-    vb = jnp.moveaxis(v.reshape(b, n_blocks, block_k, nkv, hd), 1, 0)
-    qi = jnp.arange(s)
-
-    def body(carry, inp):
-        m, l, acc = carry
-        blk_idx, k_blk, v_blk = inp
-        sco = jnp.einsum("bsngh,btnh->bngst", qg, k_blk) * scale
-        kj = blk_idx * block_k + jnp.arange(block_k)
-        mask = jnp.ones((s, block_k), bool)
-        if causal:
-            mask = mask & (kj[None, :] <= qi[:, None])
-        if window:
-            mask = mask & (qi[:, None] - kj[None, :] < window)
-        sco = jnp.where(mask[None, None, None], sco, -1e30)
-        sco = sco.astype(jnp.float32)
-        m_new = jnp.maximum(m, sco.max(axis=-1))
-        pp = jnp.exp(sco - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + pp.sum(axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bngst,btnh->bngsh", pp, v_blk.astype(jnp.float32))
-        return (m_new, l_new, acc_new), None
-
-    m0 = jnp.full((b, nkv, g, s), -1e30, jnp.float32)
-    l0 = jnp.zeros((b, nkv, g, s), jnp.float32)
-    a0 = jnp.zeros((b, nkv, g, s, hd), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, a0), (jnp.arange(n_blocks), kb, vb))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    # (b, nkv, g, s, hd) -> (b, s, nq*hd)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, s, nq * hd)
-    return out.astype(q.dtype)
-
-
 def attention_train(p, x, *, n_q, n_kv, head_dim, rope_theta=10000.0,
                     causal=True, window: Optional[int] = None,
                     prefix_len: Optional[int] = None,
                     kv_x: Optional[jnp.ndarray] = None,
                     positions: Optional[jnp.ndarray] = None,
-                    use_rope=True, return_kv=False):
+                    use_rope=True):
     """Full-sequence attention (train / prefill).
 
     ``kv_x`` switches to cross attention (keys/values from encoder states,
@@ -371,41 +314,22 @@ def attention_train(p, x, *, n_q, n_kv, head_dim, rope_theta=10000.0,
     if use_rope and kv_x is None:
         q = rope(q, positions, rope_theta)
         k = rope(k, positions, rope_theta)
-    from repro.opts import enabled as _opt
-    if (_opt("flash_attention") and kv_x is None and causal
-            and prefix_len is None and n_q == n_kv
-            and head_dim in (64, 128, 256)):
-        # TPU production path: fused blockwise Pallas attention (the (m,l,
-        # acc) stats stay in VMEM — see kernels/flash + §Perf dense-train
-        # follow-up for why the XLA-level variant below does NOT pay)
-        from repro.kernels.flash import flash_attention
-        out = flash_attention(q, k, v, causal=True, window=window or 0)
-        out = out.reshape(b, s, n_q * head_dim)
-    elif (_opt("blockwise_attention") and kv_x is None and causal
-            and prefix_len is None and t % 512 == 0):
-        # §Perf blockwise_attention: online-softmax over K blocks in XLA
-        # (measured: refuted on CPU-lowered graphs; kept for comparison)
-        out = _attention_blockwise(q, k, v, causal=True, window=window or 0)
-    else:
-        scores = _attn_scores(q, k, 1.0 / math.sqrt(head_dim))
-        if kv_x is None:
-            i = jnp.arange(s)[:, None]
-            j = jnp.arange(t)[None, :]
-            mask = jnp.ones((s, t), bool)
-            if causal:
-                mask = j <= i
-            if window is not None:
-                mask = mask & (i - j < window)
-            if prefix_len is not None:
-                mask = mask | (j < prefix_len)
-            scores = jnp.where(mask[None, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
-        out = _attn_out(probs.astype(x.dtype), v)
+    scores = _attn_scores(q, k, 1.0 / math.sqrt(head_dim))
+    if kv_x is None:
+        i = jnp.arange(s)[:, None]
+        j = jnp.arange(t)[None, :]
+        mask = jnp.ones((s, t), bool)
+        if causal:
+            mask = j <= i
+        if window is not None:
+            mask = mask & (i - j < window)
+        if prefix_len is not None:
+            mask = mask | (j < prefix_len)
+        scores = jnp.where(mask[None, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
+    out = _attn_out(probs.astype(x.dtype), v)
     out = dense(p["wo"], out)
-    out = logical_shard(out, "batch", "seq", "d_model")
-    if return_kv:
-        return out, (k, v)
-    return out
+    return logical_shard(out, "batch", "seq", "d_model")
 
 
 #: positions a cache write reads back and rewrites around its token: an
@@ -494,39 +418,11 @@ def attention_decode(p, x_t, cache: KVCache, layer, pos, *, n_q, n_kv,
     else:
         # lockstep keeps dynamic_update_slice's clamp at the buffer's end
         put_at = jnp.minimum(slot, buf - 1)
-    int8_kv = cache.k.dtype == jnp.int8
-    k_scale, v_scale = cache.k_scale, cache.v_scale
-    from repro.dist.sharding import current_mesh
-    from repro.opts import enabled as _opt
     with jax.named_scope("kv_cache"):
-        if int8_kv:
-            def q8(x_t):
-                s_t = jnp.max(jnp.abs(x_t), axis=-1, keepdims=True) / 127.0
-                s_t = jnp.maximum(s_t, 1e-12)
-                return (jnp.rint(x_t / s_t).astype(jnp.int8),
-                        s_t.astype(jnp.float32))
-            k_t_c, ks_t = q8(k_t)
-            v_t_c, vs_t = q8(v_t)
-            k = _put_token(cache.k, k_t_c, layer, put_at)
-            v = _put_token(cache.v, v_t_c, layer, put_at)
-            k_scale = _put_token(cache.k_scale, ks_t, layer, put_at)
-            v_scale = _put_token(cache.v_scale, vs_t, layer, put_at)
-        else:
-            k = _put_token(cache.k, k_t, layer, put_at)
-            v = _put_token(cache.v, v_t, layer, put_at)
-        mesh = current_mesh()
-        msize = dict(getattr(mesh, "shape", {})).get("model", 1) \
-            if mesh else 1
-        if _opt("kv_seq_shard") and n_kv % msize \
-                and k.shape[3] % msize == 0:
-            # §Perf kv_seq_shard: shard the cache SEQ dim over "model" —
-            # avoids replicating the cache when kv-head count doesn't
-            # divide the axis (GQA kv=8 / MHA 36-40 heads on a 16-way axis)
-            k = logical_shard(k, "layers", "batch", None, "kv_seq", None)
-            v = logical_shard(v, "layers", "batch", None, "kv_seq", None)
-        else:
-            k = logical_shard(k, "layers", "batch", "kv_heads", None, None)
-            v = logical_shard(v, "layers", "batch", "kv_heads", None, None)
+        k = _put_token(cache.k, k_t, layer, put_at)
+        v = _put_token(cache.v, v_t, layer, put_at)
+        k = logical_shard(k, "layers", "batch", "kv_heads", None, None)
+        v = logical_shard(v, "layers", "batch", "kv_heads", None, None)
     with jax.named_scope("attention"):
         def at_layer(a):
             a = jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
@@ -539,11 +435,6 @@ def attention_decode(p, x_t, cache: KVCache, layer, pos, *, n_q, n_kv,
                 a = jax.lax.all_gather(a, _ctx["axis"], axis=2, tiled=True)
             return a
         k_l, v_l = at_layer(k), at_layer(v)          # (B, R, buf, P·hd)
-        if int8_kv:
-            def scaled(a, s):
-                return a.astype(q.dtype) * jnp.repeat(
-                    at_layer(s).astype(q.dtype), head_dim, axis=-1)
-            k_l, v_l = scaled(k_l, k_scale), scaled(v_l, v_scale)
         # a row of the cache holds P heads side by side: each query meets
         # its own head's lanes of the row, zeros on the others' (P = 1 at
         # hd ≥ 128), so the scores read every row of heads as it lies
@@ -587,7 +478,7 @@ def attention_decode(p, x_t, cache: KVCache, layer, pos, *, n_q, n_kv,
         out = out.reshape(b, 1, n_q * head_dim)
     with jax.named_scope("attn_proj"):
         out = dense(p["wo"], out)
-    return out, KVCache(k=k, v=v, k_scale=k_scale, v_scale=v_scale)
+    return out, KVCache(k=k, v=v)
 
 
 def cross_attention_decode(p, x_t, k, v, *, n_q, n_kv, head_dim):
@@ -665,6 +556,29 @@ def moe_init(key, d_model, d_ff, n_experts, *, gated=True, dtype=jnp.float32,
     return p
 
 
+def _moe_pack(xt, gates_e, gates_w, n_experts, capacity, top_k):
+    """Sort-based dispatch: xt (T, d) → buf (E, C, d) + combine info.
+
+    Routed pairs are sorted by expert (ties keep token order) and each
+    lands at its position within its expert's segment; pairs past
+    ``capacity`` are dropped."""
+    t = xt.shape[0]
+    flat_e = gates_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    sorted_e = flat_e[order]
+    pos_in_e = jnp.arange(t * top_k) - jnp.searchsorted(
+        sorted_e, sorted_e, side="left")
+    token_of = order // top_k
+    keep = pos_in_e < capacity
+    dest = sorted_e * capacity + jnp.where(keep, pos_in_e, 0)
+    src = xt[token_of] * keep[:, None].astype(xt.dtype)
+    buf = jnp.zeros((n_experts * capacity, xt.shape[1]), xt.dtype)
+    buf = buf.at[dest].add(src)
+    weights = gates_w.reshape(-1)[order]
+    return buf.reshape(n_experts, capacity, -1), (token_of, dest, keep,
+                                                  weights)
+
+
 def moe(p, x, *, n_experts, top_k, capacity_factor=1.25, activation="silu",
         router_dtype=jnp.float32):
     """Top-k token-choice MoE with a sort-based capacity buffer.
@@ -674,26 +588,7 @@ def moe(p, x, *, n_experts, top_k, capacity_factor=1.25, activation="silu",
     through per-expert FFNs as dense einsums (MXU), and combined back with
     router weights.  Over-capacity tokens are dropped (standard GShard
     semantics); capacity_factor controls the slack.
-
-    §Perf `moe_a2a`: when a mesh is active, experts divide the model axis
-    and the flag is set, dispatch runs in an explicit shard_map with
-    all_to_all exchanges (the production EP pattern) instead of relying on
-    GSPMD to partition the scatter.
     """
-    from repro.opts import enabled as _opt
-    if _opt("moe_a2a"):
-        from repro.dist.sharding import current_mesh, in_manual_axes
-        mesh = current_mesh()
-        # never nest the a2a shard_map inside another shard_map body
-        # (k-sharded serving traces this under manual_axes)
-        if mesh is not None and not in_manual_axes() \
-                and "model" in mesh.axis_names \
-                and n_experts % mesh.shape["model"] == 0 \
-                and x.shape[1] % mesh.shape["model"] == 0:
-            return _moe_a2a(p, x, mesh, n_experts=n_experts, top_k=top_k,
-                            capacity_factor=capacity_factor,
-                            activation=activation,
-                            router_dtype=router_dtype)
     b, s, d = x.shape
     t = b * s
     xt = x.reshape(t, d)
@@ -705,28 +600,8 @@ def moe(p, x, *, n_experts, top_k, capacity_factor=1.25, activation="silu",
     capacity = int(math.ceil(t * top_k / n_experts * capacity_factor))
     capacity = max(capacity, top_k)
 
-    flat_e = top_e.reshape(-1)                                    # (T*k,)
-    # stable sort by expert id; ties keep token order
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    # position of each routed pair within its expert's segment
-    pos_in_e = jnp.arange(t * top_k) - jnp.searchsorted(
-        sorted_e, sorted_e, side="left")
-    token_of = order // top_k
-    keep = pos_in_e < capacity
-    dest = sorted_e * capacity + jnp.where(keep, pos_in_e, 0)
-
-    buf = jnp.zeros((n_experts * capacity, d), x.dtype)
-    src = xt[token_of] * keep[:, None].astype(x.dtype)
-    from repro.opts import enabled as _opt
-    if _opt("moe_dispatch_shard"):
-        # §Perf moe_dispatch_shard: pin the routed-pair tensors to the DP
-        # axes and the flat buffer to EP so GSPMD resolves the scatter as an
-        # all-to-all instead of replicate+all-reduce of (T·k, d) f32
-        src = logical_shard(src, "batch", None)
-        buf = logical_shard(buf, "experts", None)
-    buf = buf.at[dest].add(src)        # scatter-add; ≤1 writer per slot
-    buf = buf.reshape(n_experts, capacity, d)
+    buf, (token_of, dest, keep, weights) = _moe_pack(
+        xt, top_e, top_g.astype(x.dtype), n_experts, capacity, top_k)
     buf = logical_shard(buf, "experts", "capacity", "d_model")
 
     act = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[activation]
@@ -765,105 +640,10 @@ def moe(p, x, *, n_experts, top_k, capacity_factor=1.25, activation="silu",
     out_buf = out_buf.reshape(n_experts * capacity, d)
 
     # gather back and combine with gate weights
-    if _opt("moe_dispatch_shard"):
-        out_buf = logical_shard(out_buf, "experts", None)
     gathered = out_buf[dest] * keep[:, None].astype(x.dtype)      # (T*k, d)
-    weights = top_g.reshape(-1)[order].astype(x.dtype)
     contrib = gathered * weights[:, None]
-    if _opt("moe_dispatch_shard"):
-        contrib = logical_shard(contrib, "batch", None)
     out = jnp.zeros((t, d), x.dtype).at[token_of].add(contrib)
     return out.reshape(b, s, d)
-
-
-def _moe_local_pack(xt, gates_e, gates_w, n_experts, capacity, top_k):
-    """Sort-based local dispatch: xt (T, d) → buf (E, C, d) + combine info."""
-    t = xt.shape[0]
-    flat_e = gates_e.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    pos_in_e = jnp.arange(t * top_k) - jnp.searchsorted(
-        sorted_e, sorted_e, side="left")
-    token_of = order // top_k
-    keep = pos_in_e < capacity
-    dest = sorted_e * capacity + jnp.where(keep, pos_in_e, 0)
-    src = xt[token_of] * keep[:, None].astype(xt.dtype)
-    buf = jnp.zeros((n_experts * capacity, xt.shape[1]), xt.dtype)
-    buf = buf.at[dest].add(src)
-    weights = gates_w.reshape(-1)[order]
-    return buf.reshape(n_experts, capacity, -1), (token_of, dest, keep,
-                                                  weights)
-
-
-def _moe_a2a(p, x, mesh, *, n_experts, top_k, capacity_factor, activation,
-             router_dtype):
-    """Expert parallelism with explicit all_to_all (shard_map).
-
-    Layout inside the region: tokens sharded over (DP × model) — each
-    device routes a distinct token slice into an (E, C_loc, d) buffer;
-    all_to_all over "model" swaps expert-major slices so each device holds
-    ALL tokens for its E/n_model local experts; local FFN; reverse
-    all_to_all; local combine.  Exactly the token-payload exchange the
-    napkin math says is optimal (EXPERIMENTS.md §Perf pair 2).
-    """
-    from jax.sharding import PartitionSpec as P
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    n_model = mesh.shape["model"]
-    e_loc = n_experts // n_model
-    b, s, d = x.shape
-    t_loc = (b * s) // (n_model * _axis_size(mesh, dp))
-    capacity = max(int(math.ceil(t_loc * top_k / n_experts
-                                 * capacity_factor)), top_k)
-    act = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[activation]
-    gated = "w_gate" in p
-
-    def local(x_blk, router_w, *ws):
-        bb, ss, _ = x_blk.shape
-        xt = x_blk.reshape(bb * ss, d)
-        logits = (xt @ router_w.astype(router_dtype)).astype(router_dtype)
-        gates = jax.nn.softmax(logits, axis=-1)
-        top_g, top_e = jax.lax.top_k(gates, top_k)
-        top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
-        buf, (token_of, dest, keep, weights) = _moe_local_pack(
-            xt, top_e, top_g.astype(xt.dtype), n_experts, capacity, top_k)
-        # (E, C, d) -> exchange expert-major slices over the model axis
-        ex = jax.lax.all_to_all(buf, "model", split_axis=0, concat_axis=1,
-                                tiled=True)          # (e_loc, n_model·C, d)
-        if gated:
-            w_g, w_u, w_o = ws
-            h = act(jnp.einsum("ecd,edf->ecf", ex, w_g.astype(ex.dtype))) \
-                * jnp.einsum("ecd,edf->ecf", ex, w_u.astype(ex.dtype))
-        else:
-            w_i, w_o = ws
-            h = act(jnp.einsum("ecd,edf->ecf", ex, w_i.astype(ex.dtype)))
-        out_ex = jnp.einsum("ecf,efd->ecd", h, w_o.astype(ex.dtype))
-        back = jax.lax.all_to_all(out_ex, "model", split_axis=1,
-                                  concat_axis=0, tiled=True)  # (E, C, d)
-        out_rows = back.reshape(n_experts * capacity, d)[dest] \
-            * keep[:, None].astype(xt.dtype)
-        contrib = out_rows * weights[:, None].astype(xt.dtype)
-        out = jnp.zeros((bb * ss, d), xt.dtype).at[token_of].add(contrib)
-        return out.reshape(bb, ss, d)
-
-    if gated:
-        ws = (p["w_gate"], p["w_up"], p["w_out"])
-        w_specs = (P("model", None, None),) * 3
-    else:
-        ws = (p["w_in"], p["w_out"])
-        w_specs = (P("model", None, None),) * 2
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(P(dp, "model", None), P()) + w_specs,
-        out_specs=P(dp, "model", None),
-        check_vma=False)
-    return fn(x, p["router"]["w"], *ws)
-
-
-def _axis_size(mesh, axes) -> int:
-    n = 1
-    for a in axes:
-        n *= mesh.shape[a]
-    return n
 
 
 # ---------------------------------------------------------------------------

@@ -89,12 +89,8 @@ def _conv1d(x, w):
     return out
 
 
-def rglru_train(p, u, *, return_state=False):
-    """Full-sequence recurrent block.  u: (B, S, d_model).
-
-    ``return_state=True`` additionally returns RGLRUState(final h, conv
-    lookback) — bit-identical to stepping decode (parallel prefill path).
-    """
+def rglru_train(p, u):
+    """Full-sequence recurrent block.  u: (B, S, d_model)."""
     xy = dense(p["w_in"], u)
     x, y = jnp.split(xy, 2, axis=-1)
     xc = _conv1d(x, p["conv_w"])
@@ -108,15 +104,7 @@ def rglru_train(p, u, *, return_state=False):
 
     a_s, h = jax.lax.associative_scan(combine, (a, gx), axis=1)
     h = h.astype(u.dtype)
-    out = dense(p["w_out"], h * jax.nn.gelu(y))
-    if return_state:
-        cw = p["conv_w"].shape[0]
-        conv_hist = x[:, -(cw - 1):, :]
-        pad = cw - 1 - conv_hist.shape[1]
-        if pad > 0:
-            conv_hist = jnp.pad(conv_hist, ((0, 0), (pad, 0), (0, 0)))
-        return out, RGLRUState(h=h[:, -1, :], conv=conv_hist)
-    return out
+    return dense(p["w_out"], h * jax.nn.gelu(y))
 
 
 def rglru_decode(p, u_t, state: RGLRUState) -> Tuple[jnp.ndarray, RGLRUState]:

@@ -99,11 +99,8 @@ def _decay(p, xw):
     return jnp.exp(-jnp.exp(wlog))  # (…, d) ∈ (0, 1)
 
 
-def rwkv_time_mix_train(p, x, *, head_dim=64, return_state=False):
-    """x: (B, S, d) → (B, S, d); scan over time for the WKV recurrence.
-
-    ``return_state=True`` additionally returns the final WKV state (used by
-    the parallel prefill path — bit-identical to stepping decode)."""
+def rwkv_time_mix_train(p, x, *, head_dim=64):
+    """x: (B, S, d) → (B, S, d); scan over time for the WKV recurrence."""
     b, s, d = x.shape
     h = d // head_dim
     xp = _token_shift(x)
@@ -129,14 +126,11 @@ def rwkv_time_mix_train(p, x, *, head_dim=64, return_state=False):
            jnp.moveaxis(v, 1, 0).astype(jnp.float32),
            jnp.moveaxis(w, 1, 0))
     state0 = jnp.zeros((b, h, head_dim, head_dim), jnp.float32)
-    state_f, outs = jax.lax.scan(step, state0, seq)
+    _, outs = jax.lax.scan(step, state0, seq)
     out = jnp.moveaxis(outs, 0, 1)                         # (B,S,H,dv)
     out = rmsnorm(p["ln_out"], out.astype(x.dtype))
     out = (out.reshape(b, s, d) * g)
-    y = dense(p["w_o"], out)
-    if return_state:
-        return y, state_f
-    return y
+    return dense(p["w_o"], out)
 
 
 def rwkv_time_mix_decode(p, x_t, tm_shift, wkv, *, head_dim=64):

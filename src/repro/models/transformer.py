@@ -376,13 +376,6 @@ def _kv_buf(cfg, batch, buf_len, dtype, n_layers=None):
     hd = cfg.resolved_head_dim
     per_row = kv_heads_per_row(cfg.n_kv, hd)
     shape = (nl, batch, cfg.n_kv // per_row, buf_len, per_row * hd)
-    from repro.opts import enabled as _opt
-    if _opt("int8_kv"):
-        sshape = shape[:-1] + (per_row,)
-        return KVCache(k=jnp.zeros(shape, jnp.int8),
-                       v=jnp.zeros(shape, jnp.int8),
-                       k_scale=jnp.zeros(sshape, jnp.float32),
-                       v_scale=jnp.zeros(sshape, jnp.float32))
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
@@ -478,132 +471,21 @@ def prefill(cfg: ArchConfig, params, batch, max_len: int,
             cache_dtype=jnp.bfloat16):
     """Run the full prompt, return (last logits, populated cache).
 
-    Implemented as forward_train with K/V capture for attention families;
-    recurrent families scan their state.  For simplicity and HLO compactness
-    we recompute K/V into the cache buffers with a dedicated scan.
+    Recurrent families (ssm, hybrid) step the prompt through
+    :func:`decode_chunk` on a fresh cache, exactly as serving does.
+    Attention families run :func:`forward_train` for the logits and fill
+    the cache by :func:`_capture_kv`, which projects the embeddings, not
+    each layer's normed input, and applies no RoPE: that cache is the
+    right shape and dataflow for the dry-run but NOT the cache a decode
+    would build, so nothing may decode from it (DESIGN.md §6).
     """
     if cfg.family in ("dense", "moe", "vlm", "encdec"):
-        logits, cache = _prefill_attn(cfg, params, batch, max_len,
-                                      cache_dtype)
-        return logits, cache
+        return _prefill_attn(cfg, params, batch, max_len, cache_dtype)
     if cfg.family in ("ssm", "hybrid"):
-        from repro.opts import enabled
-        if enabled("parallel_prefill"):
-            if cfg.family == "ssm":
-                return _prefill_ssm_parallel(cfg, params, batch, max_len,
-                                             cache_dtype)
-            return _prefill_hybrid_parallel(cfg, params, batch, max_len,
-                                            cache_dtype)
-        # baseline: run tokens through decode_step via lax.scan (state
-        # prefill) — O(1) memory but re-reads all params per token (the xS
-        # HBM cost measured in §Perf; parallel_prefill removes it).
         tokens = batch["tokens"]
         cache = init_cache(cfg, tokens.shape[0], max_len, cache_dtype)
-
-        def step(cache, tok):
-            logits, cache = decode_step(cfg, params, cache, tok[:, None])
-            return cache, logits
-        cache, logits_seq = jax.lax.scan(step, cache, tokens.T)
-        return logits_seq[-1], cache
+        return decode_chunk(cfg, params, cache, tokens)
     raise ValueError(cfg.family)
-
-
-def _prefill_ssm_parallel(cfg, params, batch, max_len, cache_dtype):
-    """RWKV6 prefill as ONE full-sequence forward (parallel projections +
-    time-scan only for the tiny WKV state) — §Perf `parallel_prefill`."""
-    tokens = batch["tokens"]
-    s = tokens.shape[1]
-    x = _embed_tokens(cfg, params, tokens)
-
-    def block(carry, lp):
-        h = carry
-        tm_in = _norm(cfg, lp["ln_tm"], h)
-        t_out, wkv_f = rk.rwkv_time_mix_train(lp["tm"], tm_in,
-                                              head_dim=cfg.wkv_head_dim,
-                                              return_state=True)
-        h = h + t_out
-        cm_in = _norm(cfg, lp["ln_cm"], h)
-        h = h + rk.rwkv_channel_mix_train(lp["cm"], cm_in)
-        states = (tm_in[:, -1, :].astype(cache_dtype),
-                  cm_in[:, -1, :].astype(cache_dtype), wkv_f)
-        return h, states
-
-    x, (tm_s, cm_s, wkv) = jax.lax.scan(block, x, params["layers"])
-    x = _norm(cfg, params["ln_f"], x)
-    logits = unembed(params["embed"], x[:, -1:, :], cfg.vocab)[:, 0, :]
-    st = rk.RWKVState(tm_shift=tm_s, cm_shift=cm_s, wkv=wkv)
-    return logits, DecodeCache(st, jnp.asarray(s, jnp.int32))
-
-
-def _prefill_hybrid_parallel(cfg, params, batch, max_len, cache_dtype):
-    """RecurrentGemma prefill via associative-scan RG-LRU + windowed
-    attention with ring-aligned KV cache fill — §Perf `parallel_prefill`."""
-    tokens = batch["tokens"]
-    b, s = tokens.shape
-    x = _embed_tokens(cfg, params, tokens)
-    pat = cfg.block_pattern
-    ak = _attn_kwargs(cfg)
-    buf = min(max_len, cfg.local_window or max_len)
-
-    def ring_fill(k):  # (B, S, nkv, hd) -> (B, nkv/P, buf, P*hd), p%buf
-        last = k[:, -buf:]
-        pad = buf - last.shape[1]
-        if pad > 0:
-            last = jnp.pad(last, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        shift = s % buf if s >= buf else 0
-        last = jnp.roll(last, shift, axis=1)
-        per_row = kv_heads_per_row(cfg.n_kv, cfg.resolved_head_dim)
-        last = last.reshape(b, buf, cfg.n_kv // per_row, -1)
-        return jnp.swapaxes(last, 1, 2).astype(cache_dtype)
-
-    def group_block(carry, gp):
-        y = carry
-        kv_states, rec_states = [], []
-        for idx, kind in enumerate(pat):
-            sub = gp[f"b{idx}"]
-            t_in = _norm(cfg, sub["ln_t"], y)
-            if kind == "attn":
-                h, (k, v) = attention_train(
-                    sub["attn"], t_in, causal=True,
-                    window=cfg.local_window or None, return_kv=True, **ak)
-                kv_states.append(KVCache(k=ring_fill(k), v=ring_fill(v)))
-            else:
-                h, st = rg.rglru_train(sub["rec"], t_in, return_state=True)
-                rec_states.append(rg.RGLRUState(
-                    h=st.h.astype(cache_dtype),
-                    conv=st.conv.astype(cache_dtype)))
-            y = y + h
-            y = y + mlp(sub["mlp"], _norm(cfg, sub["ln_mlp"], y),
-                        activation=cfg.activation)
-        kv_st = jax.tree.map(lambda *t: jnp.stack(t), *kv_states) \
-            if kv_states else 0
-        rec_st = jax.tree.map(lambda *t: jnp.stack(t), *rec_states) \
-            if rec_states else 0
-        return y, (kv_st, rec_st)
-
-    x, (kv_g, rec_g) = jax.lax.scan(group_block, x, params["groups"])
-    # (G, per-group, ...) -> (G*per-group, ...)
-    kv = jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]), kv_g)
-    rec = jax.tree.map(lambda t: t.reshape((-1,) + t.shape[2:]), rec_g)
-    # unscanned tail (recurrent only — see decode_step)
-    tail_states = []
-    for k_i, sub in enumerate(params["tail"]):
-        t_in = _norm(cfg, sub["ln_t"], x)
-        h, st = rg.rglru_train(sub["rec"], t_in, return_state=True)
-        tail_states.append(rg.RGLRUState(h=st.h.astype(cache_dtype),
-                                         conv=st.conv.astype(cache_dtype)))
-        x = x + h
-        x = x + mlp(sub["mlp"], _norm(cfg, sub["ln_mlp"], x),
-                    activation=cfg.activation)
-    if tail_states:
-        rec = rg.RGLRUState(
-            h=jnp.concatenate([rec.h] + [st.h[None] for st in tail_states]),
-            conv=jnp.concatenate([rec.conv]
-                                 + [st.conv[None] for st in tail_states]))
-    x = _norm(cfg, params["ln_f"], x)
-    logits = unembed(params["embed"], x[:, -1:, :], cfg.vocab)[:, 0, :]
-    return logits, DecodeCache({"kv": kv, "rec": rec},
-                               jnp.asarray(s, jnp.int32))
 
 
 def _prefill_attn(cfg, params, batch, max_len, cache_dtype):

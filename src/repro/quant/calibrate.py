@@ -104,7 +104,7 @@ def forward_with_taps(cfg: ArchConfig, params, tokens) -> Tuple[jnp.ndarray,
 def _moe_with_taps(p, x, cfg: ArchConfig, act):
     """MoE forward capturing per-expert routed buffers (taps mirror
     models.layers.moe's sort-based dispatch, drop-free capacity)."""
-    from repro.models.layers import _moe_local_pack
+    from repro.models.layers import _moe_pack
     b, s, d = x.shape
     t = b * s
     e, k = cfg.n_experts, cfg.top_k
@@ -114,7 +114,7 @@ def _moe_with_taps(p, x, cfg: ArchConfig, act):
     top_g, top_e = jax.lax.top_k(gates, k)
     top_g = top_g / jnp.maximum(top_g.sum(-1, keepdims=True), 1e-9)
     capacity = max(-(-t * k // e), k)  # drop-free for calibration fidelity
-    buf, (token_of, dest, keep, weights) = _moe_local_pack(
+    buf, (token_of, dest, keep, weights) = _moe_pack(
         xt, top_e, top_g.astype(x.dtype), e, capacity, k)
     if "w_gate" in p:
         h = act(jnp.einsum("ecd,edf->ecf", buf, p["w_gate"].astype(x.dtype))) \

@@ -257,9 +257,9 @@ def params_pspecs(params, *, axis_name: str = "model"):
 def cache_pspecs(cache, *, axis_name: str = "model", shards: int):
     """(spec tree, cache_sharded) for a decode cache.
 
-    KV buffers (the 5-D ``(L, B, n_kv / P, buf, P·hd)`` leaves, incl. int8-KV
-    scale buffers) shard their buffer axis over ``axis_name`` when the
-    buffer length divides evenly; otherwise the whole cache replicates
+    KV buffers (the 5-D ``(L, B, n_kv / P, buf, P·hd)`` leaves) shard
+    their buffer axis over ``axis_name`` when the buffer length divides
+    evenly; otherwise the whole cache replicates
     (correct either way — attention gathers the sharded buffer back
     before scoring, see ``models.layers.attention_decode``).
     """

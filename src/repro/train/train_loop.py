@@ -60,17 +60,9 @@ def microbatch_grads(cfg: ArchConfig, params, batch, n_micro: int,
 
     grad_fn = jax.value_and_grad(lambda p, mb: loss_fn(cfg, p, mb))
 
-    from repro.opts import enabled as _opt
-    bf16_grads = _opt("bf16_grads")
-
     def scan_body(carry, mb):
         acc, loss_acc = carry
         loss, g = grad_fn(cast, mb)
-        if bf16_grads:
-            # §Perf bf16_grads: narrow per-micro grads before the cross-DP
-            # reduction GSPMD inserts here — halves the dominant all-reduce
-            # bytes; the f32 accumulator keeps summation exact.
-            g = jax.tree.map(lambda x: x.astype(jnp.bfloat16), g)
         acc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), acc, g)
         return (acc, loss_acc + loss), None
 

@@ -67,9 +67,9 @@ def test_roofline_fields_present():
 
 def test_train_cells_fit_reasonably():
     """Dense training cells fit v5e HBM (16 GiB/dev, small margin for the
-    32B flagship).  Baseline MoE cells exceed it by design — the documented
-    `moe_a2a` optimization brings them to ~3 GiB (experiments/perf/,
-    EXPERIMENTS.md §Perf pair 2) — so they get the wider bound here."""
+    32B flagship).  MoE cells exceed it (their dispatch is the GSPMD
+    scatter, with no explicit all-to-all), so they get the wider bound
+    here."""
     cells = _load_all()
     for (arch, shape, mesh), d in cells.items():
         if d["status"] != "ok" or shape != "train_4k":

@@ -45,7 +45,6 @@ _SCRIPT = textwrap.dedent("""
 def test_elastic_restore_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("REPRO_OPTS", None)
     r = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,
                        text=True, timeout=400, cwd=os.path.dirname(
                            os.path.dirname(os.path.abspath(__file__))),

@@ -39,15 +39,14 @@ CFG_HYB = ArchConfig(name="kvip-hyb", family="hybrid", n_layers=6,
 CFG_ENCDEC = get_config("whisper-base").reduced()
 
 #: cache kind → (config, rows, cache positions, per-slot, start positions,
-#: steps, REPRO_OPTS).  Row 1 of ``per_slot`` starts past its buffer, as an
-#: idle serving slot does: its writes are dropped.  The ring kinds wrap.
+#: steps).  Row 1 of ``per_slot`` starts past its buffer, as an idle
+#: serving slot does: its writes are dropped.  The ring kinds wrap.
 KINDS = {
-    "per_slot": (CFG, 2, 8, True, (0, 9), 5, ""),
-    "lockstep": (CFG, 2, 8, False, 2, 5, ""),
-    "ring": (CFG_RING, 2, 32, True, (0, 4), 9, ""),
-    "int8_kv": (CFG, 2, 8, True, (1, 3), 4, "int8_kv"),
-    "hybrid": (CFG_HYB, 2, 32, True, (0, 3), 8, ""),
-    "encdec": (CFG_ENCDEC, 2, 8, True, (0, 2), 4, ""),
+    "per_slot": (CFG, 2, 8, True, (0, 9), 5),
+    "lockstep": (CFG, 2, 8, False, 2, 5),
+    "ring": (CFG_RING, 2, 32, True, (0, 4), 9),
+    "hybrid": (CFG_HYB, 2, 32, True, (0, 3), 8),
+    "encdec": (CFG_ENCDEC, 2, 8, True, (0, 2), 4),
 }
 
 
@@ -85,16 +84,13 @@ def _decode_run(cfg, rows, max_len, per_slot, start, steps):
 
 @pytest.mark.parametrize("kind", list(KINDS))
 def test_carried_cache_matches_layer_sliced_scan(kind, monkeypatch):
-    cfg, rows, max_len, per_slot, start, steps, opts = KINDS[kind]
-    monkeypatch.setenv("REPRO_OPTS", opts)
+    cfg, rows, max_len, per_slot, start, steps = KINDS[kind]
     run = functools.partial(_decode_run, cfg, rows, max_len, per_slot,
                             start, steps)
     logits, cache = run()
     monkeypatch.setattr(transformer, "attention_decode",
                         _layer_sliced(layers.attention_decode))
     ref_logits, ref_cache = run()
-    if opts == "int8_kv":
-        assert cache.kv.k.dtype == jnp.int8
     for a, b in zip(logits, ref_logits):
         np.testing.assert_array_equal(a, b)
     for a, b in zip(jax.tree.leaves(cache), jax.tree.leaves(ref_cache)):

@@ -249,7 +249,6 @@ _SCRIPT = textwrap.dedent("""
 def test_mesh_serving_differential_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("REPRO_OPTS", None)
     r = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,
                        text=True, timeout=580, cwd=os.path.dirname(
                            os.path.dirname(os.path.abspath(__file__))),

@@ -85,7 +85,6 @@ _SCRIPT = textwrap.dedent("""
 def test_sharded_obs_parity_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
-    env.pop("REPRO_OPTS", None)
     env.pop("REPRO_OBS", None)
     r = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,
                        text=True, timeout=580, cwd=os.path.dirname(
