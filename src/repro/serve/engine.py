@@ -40,7 +40,10 @@ time-to-first-token and time-per-output-token — the latency axes
 benchmarks/serve_bench.py reports p50/p99 over.  Per-round timing hooks
 land in ``engine.round_stats`` (static) / ``engine.step_stats``
 (continuous); ``prefill_s`` is device wall-clock up to the last prefill
-logits being ready — the host-side argmax transfer is decode-side.
+logits being ready — the pick of the first token is decode-side.  The
+continuous engine picks every token on the device (``serve_greedy``) and
+copies only the int32 ids to the host; the static engine keeps its host
+argmax, so the differential tests hold the device pick against numpy's.
 
 Observability (DESIGN.md §11): the engines open ``repro.obs`` spans
 around their host work.  While a JAX profiler session collects, every
@@ -51,19 +54,19 @@ engine's tree is ``serve.step`` ⊃ ``serve.admit`` (one burst) ⊃
 ``serve.step`` ⊃ ``serve.decode`` ⊃ {``.dispatch``, ``.wait``, ``.sync``,
 ``.commit``}; the static engine opens ``serve.prefill`` and
 ``serve.decode`` per round.  The jitted programs are named
-(``serve_decode_step``, ``serve_prefill_chunk``, ``serve_init_cache``,
-``serve_admit_row``, ``cache_write_slot``, ``cache_reset_slot``), so each
-device op's module says which program it belongs to.  When ``repro.obs``
-is enabled the same spans become Chrome events that adopt the SAME
-perf_counter stamps that back RoundStats/StepStats/Request (per-row
-admission spans on slot-numbered lanes), request lifecycle lands as
-trace instants (``serve.request.arrival`` / ``first_token`` /
-``finish``) plus ``repro_serve_ttft_seconds`` /
-``repro_serve_tpot_seconds`` histograms, queue depth and slot occupancy
-are gauges, and admissions/evictions/tokens are counters.  With obs
-disabled and no profiler session every hook is a no-op behind one check:
-token streams and stats are byte-identical either way (asserted in
-tests/test_obs_integration.py).
+(``serve_decode_step``, ``serve_prefill_chunk``, ``serve_greedy``,
+``serve_init_cache``, ``serve_admit_row``, ``cache_write_slot``,
+``cache_reset_slot``), so each device op's module says which program it
+belongs to.  When ``repro.obs`` is enabled the same spans become Chrome
+events that adopt the SAME perf_counter stamps that back
+RoundStats/StepStats/Request (per-row admission spans on slot-numbered
+lanes), request lifecycle lands as trace instants
+(``serve.request.arrival`` / ``first_token`` / ``finish``) plus
+``repro_serve_ttft_seconds`` / ``repro_serve_tpot_seconds`` histograms,
+queue depth and slot occupancy are gauges, and admissions/evictions/tokens
+are counters.  With obs disabled and no profiler session every hook is a
+no-op behind one check: token streams and stats are byte-identical either
+way (asserted in tests/test_obs_integration.py).
 
 Resilience (DESIGN.md §12): both engines accept an optional
 ``resilience=ResilienceConfig(...)`` enabling per-request deadlines with
@@ -142,7 +145,9 @@ class Request:
 
     @property
     def ttft_s(self) -> Optional[float]:
-        """Time to first token: queue wait + prefill + first argmax."""
+        """Time to first token: queue wait + prefill + the first pick (on
+        the device in the continuous engine, a host argmax in the static
+        one) and its ids to the host."""
         if self.arrival_s is None or self.first_token_s is None:
             return None
         return self.first_token_s - self.arrival_s
@@ -165,7 +170,7 @@ class RoundStats:
     prompt_len: int
     prefill_calls: int               # device dispatches spent on the prompt
     prefill_s: float                 # up to last prefill logits ready (the
-                                     # host argmax transfer is decode-side)
+                                     # first token's pick is decode-side)
     decode_calls: int                # generation decode dispatches
     decode_s: float
     new_tokens: int                  # tokens emitted across the batch
@@ -227,6 +232,17 @@ def _serving_programs(cfg: ArchConfig):
 
     return (jax.jit(serve_decode_step, donate_argnums=(1,)),
             jax.jit(serve_prefill_chunk, donate_argnums=(1,)))
+
+
+@jax.jit
+def serve_greedy(logits):
+    """The greedy token of each row of ``logits`` (..., vocab), as int32 on
+    the device: the continuous engine's one pick, so that only the ids
+    leave the chip and the logits never do.  Ties go to the first index
+    among equal maxima, numpy's rule, on the same values the host argmax
+    of the static engine reads."""
+    with jax.named_scope("lm_head"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
 class _EngineBase:
@@ -692,9 +708,10 @@ class ContinuousEngine(_EngineBase):
     lockstep chunked ``decode_chunk`` stream, finishes ragged tails
     per-row, and grafts each row into its slot — then (ii) issues ONE
     lockstep ``decode_step`` over all slots (idle slots feed a pad token;
-    their rows are isolated garbage), appends each active slot's argmax
-    token, and (iii) evicts slots whose budget filled, freeing them for
-    the next step's admissions.
+    their rows are isolated garbage), picks each slot's greedy token on the
+    device (``serve_greedy``), copies only the (B,) int32 ids to the host
+    and appends each active slot's, and (iii) evicts slots whose budget
+    filled, freeing them for the next step's admissions.
 
     Token streams are exactly those of the static reference: prefill is
     decode_chunk (bit-exact vs per-token), attention/MLP decode is
@@ -787,9 +804,11 @@ class ContinuousEngine(_EngineBase):
         ``prefill_s`` bills the burst from before its sub-cache is
         allocated (``serve_init_cache``) to logits-ready, and each tail
         from its first dispatch to logits-ready: the allocation counts as
-        prefill, the row slices, grafts and host argmaxes do not.  In a
-        device trace the allocation shows apart, as the ``kv_cache`` op of
-        the ``serve_init_cache`` module inside ``serve.admit.prefill``.
+        prefill, the row slices, grafts and first-token picks do not (each
+        pick runs on the device, ``serve_greedy``, and only its id leaves
+        the chip).  In a device trace the allocation shows apart, as the
+        ``kv_cache`` op of the ``serve_init_cache`` module inside
+        ``serve.admit.prefill``.
         """
         g = len(pairs)
         reqs = [r for _, r in pairs]
@@ -838,8 +857,14 @@ class ContinuousEngine(_EngineBase):
                     self.prefill_s += t_tail_end - t_tail
                     calls += c_tail
                 with obs.span("serve.admit.first_token", tid=slot,
-                              slot=slot, rid=req.rid):
-                    first = int(np.argmax(np.asarray(log_i)[0]))
+                              slot=slot, rid=req.rid) as sp:
+                    ids = serve_greedy(log_i)
+                    first = int(np.asarray(ids)[0])
+                    sp.set(bytes=ids.nbytes)
+                if obs.enabled():
+                    obs.counter("repro_serve_host_bytes_total",
+                                engine="continuous",
+                                site="first_token").inc(ids.nbytes)
                 with obs.span("serve.admit.graft", tid=slot, slot=slot,
                               rid=req.rid):
                     self.cache = self._write_slot(
@@ -1000,25 +1025,31 @@ class ContinuousEngine(_EngineBase):
 
     def _decode_round(self, active: List[int],
                       finished: List[Request]) -> int:
-        """One lockstep decode over every slot: dispatch, wait, the logits
-        to the host and their argmax, then the token appends and
-        evictions.  ``decode_s`` bills dispatch to argmax.  Returns the
-        tokens emitted."""
+        """One lockstep decode over every slot: dispatch the step and the
+        greedy pick on its logits (``serve_greedy``), wait, copy the (B,)
+        int32 ids to the host, then the token appends and evictions.  The
+        logits never leave the chip.  ``decode_s`` bills dispatch to ids
+        on the host.  Returns the tokens emitted."""
         with obs.span("serve.decode", engine="continuous",
                       slots=active) as sp:
             td = self._now()
             with obs.span("serve.decode.dispatch"):
                 logits, new_cache = self._retry("serve.decode",
                                                 self._decode_dispatch)
-                # the logits leave for the host as soon as the step ends,
-                # not after the wait below has woken this thread
-                logits.copy_to_host_async()
+                ids = serve_greedy(logits)
+                # the ids leave for the host as soon as the pick ends, not
+                # after the wait below has woken this thread
+                ids.copy_to_host_async()
             self.cache = new_cache
             with obs.span("serve.decode.wait"):
-                jax.block_until_ready(logits)
-            with obs.span("serve.decode.sync"):
-                last = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
+                jax.block_until_ready(ids)
+            with obs.span("serve.decode.sync", bytes=ids.nbytes):
+                last = np.asarray(ids)
             t_tok = self._now()
+            if obs.enabled():
+                obs.counter("repro_serve_host_bytes_total",
+                            engine="continuous",
+                            site="decode").inc(ids.nbytes)
             sp.stamp(td, t_tok)
             self.decode_calls += 1
             self.decode_s += t_tok - td

@@ -23,6 +23,7 @@ from repro.models import (decode_chunk, decode_step, init_cache,
                           init_params, split_tree)
 from repro.quant import quantize_params_tree
 from repro.serve import ContinuousEngine, EngineConfig, Request
+from repro.serve.engine import serve_greedy
 
 CFG = ArchConfig(name="s", family="dense", n_layers=2, d_model=32,
                  n_heads=2, n_kv=2, d_ff=64, vocab=64, head_dim=16)
@@ -30,8 +31,9 @@ PROMPTS = [5, 8, 4, 9]          # a burst of two with a ragged tail, then
 MAX_NEW = 3                     # admissions into freed slots
 #: the engine's programs, as ``hlo_module`` names them
 ENGINE_MODULES = {"jit_serve_decode_step", "jit_serve_prefill_chunk",
-                  "jit_serve_init_cache", "jit_serve_admit_row",
-                  "jit_cache_write_slot", "jit_cache_reset_slot"}
+                  "jit_serve_greedy", "jit_serve_init_cache",
+                  "jit_serve_admit_row", "jit_cache_write_slot",
+                  "jit_cache_reset_slot"}
 
 
 @pytest.fixture(autouse=True)
@@ -58,20 +60,24 @@ def _engine(params):
 
 
 def _profile(tmp_path):
-    """Host spans (name, start, end) and device-op modules of the trace."""
+    """Host spans (name, start, end), device-op modules, and each host
+    span's ``bytes=`` argument by span name."""
     from jax.profiler import ProfileData
     path = sorted(glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True))[-1]
-    spans, modules = [], set()
+    spans, modules, sent = [], set(), {}
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
+                stats = dict(ev.stats)
                 if ev.name.startswith("serve."):
                     spans.append((ev.name, ev.start_ns,
                                   ev.start_ns + ev.duration_ns))
-                stats = dict(ev.stats)
+                    if "bytes" in stats:
+                        sent.setdefault(ev.name, []).append(
+                            int(stats["bytes"]))
                 if "hlo_module" in stats:
                     modules.add(stats["hlo_module"])
-    return spans, modules
+    return spans, modules, sent
 
 
 def _parent(spans, child):
@@ -88,7 +94,7 @@ def test_engine_spans_nest_on_the_profiler_clock(tmp_path):
     assert not obs.enabled()
     with jax.profiler.trace(str(tmp_path)):
         eng.run_until_done()
-    spans, modules = _profile(tmp_path)
+    spans, modules, sent = _profile(tmp_path)
     by_name = {}
     for sp in spans:
         by_name.setdefault(sp[0], []).append(sp)
@@ -103,6 +109,10 @@ def test_engine_spans_nest_on_the_profiler_clock(tmp_path):
             == {"serve.decode"}
     assert {_parent(spans, sp) for sp in by_name["serve.decode"]} \
         == {"serve.step"}
+    # only the picked ids reach the host: (n_slots,) int32 per decode,
+    # (1,) int32 per first token
+    assert sent["serve.decode.sync"] == [eng.n_slots * 4] * eng.decode_calls
+    assert sent["serve.admit.first_token"] == [4] * len(PROMPTS)
     # admissions: a burst span per admitting step, its children inside it
     admitting = sum(1 for st in eng.step_stats if st.admitted)
     assert len(by_name["serve.admit"]) == admitting
@@ -128,6 +138,7 @@ def test_engine_programs_are_named():
     lowered = [
         eng._decode.lower(eng.params, eng.cache, tok),
         eng._decode_chunk.lower(eng.params, eng.cache, tok),
+        serve_greedy.lower(logits),
         eng._init_sub.lower(2),
         eng._admit_row.lower(sub, logits, np.int32(1)),
         eng._write_slot.lower(eng.cache, eng._init_sub(1), np.int32(0)),

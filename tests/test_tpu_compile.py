@@ -13,7 +13,8 @@ packed kernel's Mosaic module is read as it is serialized, to check the
 operand types of its MXU contractions.  The serving test compiles the
 engine's decode step and admission chunk at minitron-8b's full size and
 reads their memory from the compiler; another holds both configurations'
-serving programs to updating the donated KV cache in place.
+serving programs to updating the donated KV cache in place, and a third
+compiles the engine's greedy pick at both configurations' logits.
 """
 import re
 import sys
@@ -257,3 +258,21 @@ def test_serving_programs_update_cache_in_place(one_chip, monkeypatch, name,
         if dims == stack:
             assert op in _PASS_ON + _IN_PLACE or (
                 op == "fusion" and root in _IN_PLACE), (op, root, dims)
+
+
+@pytest.mark.parametrize("name", ["minicpm-2b.int4", "minitron-8b.int4"])
+def test_greedy_pick_compiles_for_v5e(one_chip, name):
+    """The engine's device pick at each benchmark configuration's decode
+    step (slots x vocab bf16 logits) and first token (1 x vocab): its
+    output, all that leaves the chip, is one int32 per row (a buffer of
+    one padded tile on the device), and it makes no copy of the logits."""
+    from repro.serve.engine import serve_greedy
+    spec = _serving_setup(one_chip, name)[0]
+    for rows in (spec.slots, 1):
+        logits = jax.ShapeDtypeStruct((rows, spec.vocab), jnp.bfloat16,
+                                      sharding=one_chip)
+        out = jax.eval_shape(serve_greedy, logits)
+        assert (out.shape, out.dtype) == ((rows,), jnp.int32)
+        mem = serve_greedy.lower(logits).compile().memory_analysis()
+        assert mem.output_size_in_bytes <= 4096, mem
+        assert mem.temp_size_in_bytes < rows * spec.vocab * 2, mem
